@@ -406,4 +406,6 @@ def main(argv=None) -> int:
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from repro.utils.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     sys.exit(main())

@@ -18,6 +18,8 @@ def main() -> None:
     ap.add_argument("--art-dir", default="experiments/dryrun")
     args, _ = ap.parse_known_args()
 
+    from repro.utils.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     from benchmarks.kernel_bench import bench_kernels
     from benchmarks.paper_tables import (bench_fig5_fig6, bench_table5,
                                          bench_table7)

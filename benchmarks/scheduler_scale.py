@@ -764,5 +764,7 @@ if __name__ == "__main__":
                     help="also run the (slower) per-scenario online "
                          "competitive-ratio section")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     for line in bench_scheduler_scale(with_online_scenarios=args.online)[1]:
         print(line)

@@ -755,6 +755,13 @@ def _per_instance_mpt(machines_per_tier, B: int):
     return [(int(c), int(e)) for c, e in seq]
 
 
+def kernel_regime(slots: int, rows: int) -> str:
+    """The batched search's regime for S movable slots in a batch padded
+    to `rows` rows: "round" when the movable slots fill at least half the
+    rows, "pass" when background dominates (DESIGN.md §12)."""
+    return "round" if 2 * slots >= rows else "pass"
+
+
 def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
                         initial: Sequence[Sequence[int]] | None = None,
                         *, max_rounds: int | None = None,
@@ -874,7 +881,7 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
     # width-1 movable-slot passes. Both sides of the threshold are a
     # pure function of the batch's padded shape, so every ward of one
     # call follows one regime and B = 1 replays it exactly.
-    mode = "round" if 2 * mov_idx.shape[1] >= n_max else "pass"
+    mode = kernel_regime(mov_idx.shape[1], n_max)
     assign, totals, _ = _tabu_run_batched(
         assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
         np.int32(max_rounds), busy_c, busy_e, objective,

@@ -1,17 +1,13 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Models call these, never pallas_call directly. Each op dispatches to the
-Pallas kernel when shapes are block-compatible (and runs it in interpret
-mode off-TPU), falling back to the pure-jnp oracle for tiny/ragged shapes —
-so the same model code runs in CPU smoke tests and TPU production.
-
-``use_pallas`` can be forced via the REPRO_FORCE_PALLAS / REPRO_NO_PALLAS
-env vars (tests use these to pin the path under test).
+Models call these, never pallas_call directly. The platform picks the
+path: on a TPU each op runs its Pallas kernel when shapes are
+block-compatible, and the pure-jnp oracle otherwise; on any other backend
+every op runs the oracle (Pallas interpret mode is correct but slow). So
+the same model code runs in CPU tests and in TPU production, and the
+kernels' interpret-mode tests call them directly.
 """
 from __future__ import annotations
-
-import os
-from typing import Optional
 
 import jax
 
@@ -26,12 +22,6 @@ __all__ = ["attention", "lstm_step", "ssm", "mlstm", "flash_attention",
 
 
 def _pallas_enabled() -> bool:
-    if os.environ.get("REPRO_NO_PALLAS"):
-        return False
-    if os.environ.get("REPRO_FORCE_PALLAS"):
-        return True
-    # Pallas interpret mode on CPU is correct but slow; default to the oracle
-    # off-TPU unless forced. On TPU the kernels are the default.
     return jax.default_backend() == "tpu"
 
 

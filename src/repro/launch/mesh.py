@@ -15,11 +15,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     ("pod","data","model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = jax.device_count()
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(ndim: int):
+    """Auto axis types: the sharding policy steers layouts with
+    with_sharding_constraint, which only accepts Auto mesh axes (JAX's
+    make_mesh defaults to Explicit ones)."""
+    return (jax.sharding.AxisType.Auto,) * ndim

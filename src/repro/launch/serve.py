@@ -37,6 +37,7 @@ from repro.core.tiers import CC, ED, ES, paper_tiers, tpu_tiers
 from repro.data import icu
 from repro.models.lstm import ICULSTM
 from repro.serving.engine import ClassifierEngine
+from repro.utils.compile_cache import enable_compilation_cache
 
 
 def calibrate(tiers, engines, unit_records: int = 16):
@@ -77,12 +78,20 @@ def _setup_fleet(tiers_kind, cloud_machines, edge_machines):
             tiers[tid] = dataclasses.replace(tiers[tid], machines=count)
     machines_per_tier = {tid: t.machines for tid, t in tiers.items()
                          if not t.private}
+    engines = icu_engines()
+    return tiers, machines_per_tier, engines, calibrate(tiers, engines)
+
+
+def icu_engines():
+    """{workload config: ClassifierEngine} for the paper's three ICU
+    LSTMs, with weights made from a key fixed per workload, so every
+    call builds the same models."""
     engines = {}
     for wl_cfg in ICU_WORKLOADS:
         model = ICULSTM(wl_cfg)
         key = jax.random.PRNGKey(zlib.crc32(wl_cfg.name.encode()))
         engines[wl_cfg] = ClassifierEngine(model, model.init(key))
-    return tiers, machines_per_tier, engines, calibrate(tiers, engines)
+    return engines
 
 
 def _validate_quantum(quantum) -> None:
@@ -532,6 +541,7 @@ def main():
     args = ap.parse_args()
     if args.contention and args.wards <= 0:
         ap.error("--contention requires --wards N (N > 0)")
+    enable_compilation_cache()
     if args.metro:
         run_metro(wards=args.wards or None, hours=args.metro_hours,
                   seed=args.seed,

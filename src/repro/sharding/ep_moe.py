@@ -31,21 +31,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.sharding import policy
 
-# jax.shard_map moved out of the top-level namespace and back again across
-# releases, and its replication-check kwarg was renamed check_rep ->
-# check_vma independently of that move — so pick the kwarg by the resolved
-# function's actual signature, not by where it lives.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect
-
-_SHARD_MAP_KW = {
-    "check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep": False}
-
-
 def ep_group_pairs(e: int, r: int):
     return [[i * r + j for j in range(r)] for i in range(e)]
 
@@ -90,8 +75,8 @@ def ep_moe_ffn(experts, router, h, cfg, mesh):
                 P(None, None))                 # router
     out_specs = (P(b_spec, seq_spec, None), P())
 
-    @partial(_shard_map, mesh=mesh, in_specs=in_specs,
-             out_specs=out_specs, **_SHARD_MAP_KW)
+    @partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+             out_specs=out_specs, check_vma=False)
     def run(h_loc, wg, wu, wd, rt):
         hf = h_loc.reshape(-1, d)                           # (T, d)
         t = hf.shape[0]

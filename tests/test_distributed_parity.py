@@ -37,7 +37,8 @@ def run(sharded):
     o = optimizer.init(p)
     losses = []
     if sharded:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         p_sh = policy.to_shardings(policy.param_specs(p, mesh), mesh)
         o_sh = policy.to_shardings(policy.param_specs(o, mesh), mesh)
         p = jax.device_put(p, p_sh)

@@ -50,7 +50,8 @@ x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 64))
 y_tp, aux_tp = blocks._moe_ffn(p_tp, x, cfg_tp)
 
 # EP path under a (1, 8) mesh
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = jax.make_mesh((1, 8), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh, policy.activation_policy(mesh):
     y_ep, aux_ep = jax.jit(lambda p, x: blocks._moe_ffn(p, x, cfg_ep))(p_ep, x)
 
