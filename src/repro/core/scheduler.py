@@ -36,6 +36,7 @@ from repro.core.simulator import (MACHINES, FleetSchedule, JobSpec,
                                   _fleet_mpts, machine_free_times, simulate,
                                   simulate_fleet)
 from repro.core.tiers import CC, ED, ES
+from repro.utils import spans
 
 # above this many jobs, `search` uses the jitted JAX neighbourhood search
 JAX_SEARCH_THRESHOLD = 64
@@ -329,46 +330,50 @@ def search(jobs: Sequence[JobSpec],
     every event, and without the bucketing each drift would be a fresh
     multi-second trace.
     """
-    n = len(jobs)
-    mpt = dict(machines_per_tier or {})
-    mpt_jax = (int(mpt.get(CC, 1)), int(mpt.get(ES, 1)))
-    n_res = sum(len(v) for v in (reserved or {}).values())
-    n_mov = n - (sum(map(bool, frozen)) if frozen is not None else 0)
-    rows = _bucket16(n + n_res)
-    shape = (rows, min(rows, _bucket16(n_mov)), mpt_jax, objective)
-    if jax_threshold is None:
-        use_jax = (n > JAX_SEARCH_THRESHOLD and _accelerator_backend()) \
-            or shape in _COMPILED_SHAPES
-    else:
-        use_jax = n > jax_threshold
-    if not use_jax:
-        return neighborhood_search(jobs, initial=initial,
-                                   max_count=max_count, objective=objective,
-                                   machines_per_tier=machines_per_tier,
-                                   busy_until=busy_until, frozen=frozen,
-                                   reserved=reserved)
-    from repro.core import scheduler_jax   # lazy: keep jax off small paths
-    if frozen is not None and any(frozen) and initial is None:
-        raise ValueError("frozen jobs require an explicit initial "
-                         "assignment carrying their pinned tiers")
-    if n_res and initial is None:
-        raise ValueError("reservations require an explicit initial "
-                         "assignment (greedy init ignores their occupancy)")
-    assign0 = initial or greedy_schedule(
-        jobs, machines_per_tier=machines_per_tier, busy_until=busy_until)
-    busy_jax = tuple(machine_free_times(busy_until, t, m)
-                     for t, m in zip((CC, ES), mpt_jax))
-    _, assigns = scheduler_jax.tabu_search_batched(
-        [jobs], [[MACHINES.index(t) for t in assign0]],
-        max_rounds=max(max_count, 1), objective=objective,
-        machines_per_tier=[mpt_jax], busy_until=[busy_jax],
-        frozen=None if frozen is None else [list(frozen)],
-        reserved=None if reserved is None else [reserved],
-        pad_to=rows)
-    _note_shape(shape)
-    return simulate(jobs, [MACHINES[int(m)] for m in assigns[0]],
-                    machines_per_tier=machines_per_tier,
-                    busy_until=busy_until, reserved=reserved)
+    with spans.span("scheduler.search"):
+        n = len(jobs)
+        mpt = dict(machines_per_tier or {})
+        mpt_jax = (int(mpt.get(CC, 1)), int(mpt.get(ES, 1)))
+        n_res = sum(len(v) for v in (reserved or {}).values())
+        n_mov = n - (sum(map(bool, frozen)) if frozen is not None else 0)
+        rows = _bucket16(n + n_res)
+        shape = (rows, min(rows, _bucket16(n_mov)), mpt_jax, objective)
+        if jax_threshold is None:
+            use_jax = (n > JAX_SEARCH_THRESHOLD
+                       and _accelerator_backend()) \
+                or shape in _COMPILED_SHAPES
+        else:
+            use_jax = n > jax_threshold
+        if not use_jax:
+            return neighborhood_search(
+                jobs, initial=initial, max_count=max_count,
+                objective=objective, machines_per_tier=machines_per_tier,
+                busy_until=busy_until, frozen=frozen, reserved=reserved)
+        from repro.core import scheduler_jax   # lazy: jax off small paths
+        if frozen is not None and any(frozen) and initial is None:
+            raise ValueError("frozen jobs require an explicit initial "
+                             "assignment carrying their pinned tiers")
+        if n_res and initial is None:
+            raise ValueError("reservations require an explicit initial "
+                             "assignment (greedy init ignores their "
+                             "occupancy)")
+        assign0 = initial or greedy_schedule(
+            jobs, machines_per_tier=machines_per_tier,
+            busy_until=busy_until)
+        busy_jax = tuple(machine_free_times(busy_until, t, m)
+                         for t, m in zip((CC, ES), mpt_jax))
+        _, assigns = scheduler_jax.tabu_search_batched(
+            [jobs], [[MACHINES.index(t) for t in assign0]],
+            max_rounds=max(max_count, 1), objective=objective,
+            machines_per_tier=[mpt_jax], busy_until=[busy_jax],
+            frozen=None if frozen is None else [list(frozen)],
+            reserved=None if reserved is None else [reserved],
+            pad_to=rows)
+        _note_shape(shape)
+        with spans.span("scheduler.rescore"):
+            return simulate(jobs, [MACHINES[int(m)] for m in assigns[0]],
+                            machines_per_tier=machines_per_tier,
+                            busy_until=busy_until, reserved=reserved)
 
 
 def search_batched(problems: Sequence[Sequence[JobSpec]],
@@ -440,48 +445,51 @@ def search_batched(problems: Sequence[Sequence[JobSpec]],
                        busy_until=b)
                 for jobs, m, b, init, fr, rv
                 in zip(problems, mpts, busys, inits, frozens, reserveds)]
-    from repro.core import scheduler_jax   # lazy: keep jax off small paths
-    if initial is None and frozen is not None \
-            and any(fr is not None and any(fr) for fr in frozens):
-        raise ValueError("frozen jobs require an explicit initial "
-                         "assignment carrying their pinned tiers")
-    if initial is not None:
-        # the batched backend needs an initial for every ward or none —
-        # fill the gaps with the greedy initial the solo path would use,
-        # so mixed-initial calls behave the same on both dispatch paths
-        inits = [init if init is not None else greedy_schedule(
-            jobs, machines_per_tier=m, busy_until=b)
-            for jobs, m, b, init in zip(problems, mpts, busys, inits)]
-    pairs = [(int(dict(m or {}).get(CC, 1)), int(dict(m or {}).get(ES, 1)))
-             for m in mpts]
-    busy_pairs = [tuple(machine_free_times(b, t, mm)
-                        for t, mm in zip((CC, ES), pair))
-                  for b, pair in zip(busys, pairs)]
-    # bucket the padded row count (§12) so metro multi-ward replans with
-    # drifting sizes land on a handful of compiled shapes, and record the
-    # dispatch so `compiled_shape_stats` sees batched traffic too
-    raw_rows = max((len(jobs) + sum(len(v) for v in (rv or {}).values())
-                    for jobs, rv in zip(problems, reserveds)), default=0)
-    rows = _bucket16(raw_rows) if raw_rows else None
-    _, assigns = scheduler_jax.tabu_search_batched(
-        problems,
-        None if initial is None else
-        [[MACHINES.index(t) for t in init] for init in inits],
-        max_rounds=max(max_count, 1),
-        objective=objective, machines_per_tier=pairs,
-        busy_until=busy_pairs,
-        frozen=None if frozen is None else frozens,
-        reserved=None if reserved is None else reserveds,
-        pad_to=rows)
-    if raw_rows:
-        n_mov = max(len(jobs) - (sum(map(bool, fr)) if fr is not None
-                                 else 0)
-                    for jobs, fr in zip(problems, frozens))
-        _note_shape(_batched_shape(B, rows, n_mov, pairs, objective))
-    return [simulate(jobs, [MACHINES[int(i)] for i in a],
-                     machines_per_tier=m, busy_until=b, reserved=rv)
-            for jobs, a, m, b, rv
-            in zip(problems, assigns, mpts, busys, reserveds)]
+    with spans.span("scheduler.search"):
+        from repro.core import scheduler_jax   # lazy: jax off small paths
+        if initial is None and frozen is not None \
+                and any(fr is not None and any(fr) for fr in frozens):
+            raise ValueError("frozen jobs require an explicit initial "
+                             "assignment carrying their pinned tiers")
+        if initial is not None:
+            # the batched backend needs an initial for every ward or none —
+            # fill the gaps with the greedy initial the solo path would use,
+            # so mixed-initial calls behave the same on both dispatch paths
+            inits = [init if init is not None else greedy_schedule(
+                jobs, machines_per_tier=m, busy_until=b)
+                for jobs, m, b, init in zip(problems, mpts, busys, inits)]
+        pairs = [(int(dict(m or {}).get(CC, 1)),
+                  int(dict(m or {}).get(ES, 1))) for m in mpts]
+        busy_pairs = [tuple(machine_free_times(b, t, mm)
+                            for t, mm in zip((CC, ES), pair))
+                      for b, pair in zip(busys, pairs)]
+        # bucket the padded row count (§12) so metro multi-ward replans with
+        # drifting sizes land on a handful of compiled shapes, and record the
+        # dispatch so `compiled_shape_stats` sees batched traffic too
+        raw_rows = max((len(jobs)
+                        + sum(len(v) for v in (rv or {}).values())
+                        for jobs, rv in zip(problems, reserveds)), default=0)
+        rows = _bucket16(raw_rows) if raw_rows else None
+        _, assigns = scheduler_jax.tabu_search_batched(
+            problems,
+            None if initial is None else
+            [[MACHINES.index(t) for t in init] for init in inits],
+            max_rounds=max(max_count, 1),
+            objective=objective, machines_per_tier=pairs,
+            busy_until=busy_pairs,
+            frozen=None if frozen is None else frozens,
+            reserved=None if reserved is None else reserveds,
+            pad_to=rows)
+        if raw_rows:
+            n_mov = max(len(jobs) - (sum(map(bool, fr)) if fr is not None
+                                     else 0)
+                        for jobs, fr in zip(problems, frozens))
+            _note_shape(_batched_shape(B, rows, n_mov, pairs, objective))
+        with spans.span("scheduler.rescore"):
+            return [simulate(jobs, [MACHINES[int(i)] for i in a],
+                             machines_per_tier=m, busy_until=b, reserved=rv)
+                    for jobs, a, m, b, rv
+                    in zip(problems, assigns, mpts, busys, reserveds)]
 
 
 # --------------------------------------------- contention-aware fleet search

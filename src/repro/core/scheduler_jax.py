@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core.simulator import JobSpec, Reservation
 from repro.core.tiers import CC, ED, ES
+from repro.utils import spans
 
 N_MACHINES = 3
 
@@ -817,78 +818,87 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
     B = len(batch_jobs)
     if B == 0:
         return np.zeros((0,)), []
-    if reserved is None:
-        reserved = [None] * B
-    elif initial is None and any(r for r in reserved):
-        raise ValueError("reservations require an explicit initial "
-                         "assignment (greedy init ignores their "
-                         "occupancy)")
-    rsv = [_reservation_rows(r) for r in reserved]
-    sizes = [len(jobs) for jobs in batch_jobs]
-    rows = [nb + rr.shape[0] for nb, (rr, _) in zip(sizes, rsv)]
-    n_max = max(rows)
-    if pad_to is not None:
-        n_max = max(n_max, int(pad_to))
-    if frozen is not None and initial is None:
-        raise ValueError("frozen jobs require an explicit initial "
-                         "assignment (greedy init would reassign them)")
-    mpts = _per_instance_mpt(machines_per_tier, B)
-    m_max = (max(c for c, _ in mpts), max(e for _, e in mpts))
-    if busy_until is None:
-        busy_until = [None] * B
-    if n_max == 0:
-        return np.zeros((B,)), [np.zeros((0,), np.int64) for _ in range(B)]
+    with spans.span("scheduler.pack"):
+        if reserved is None:
+            reserved = [None] * B
+        elif initial is None and any(r for r in reserved):
+            raise ValueError("reservations require an explicit initial "
+                             "assignment (greedy init ignores their "
+                             "occupancy)")
+        rsv = [_reservation_rows(r) for r in reserved]
+        sizes = [len(jobs) for jobs in batch_jobs]
+        rows = [nb + rr.shape[0] for nb, (rr, _) in zip(sizes, rsv)]
+        n_max = max(rows)
+        if pad_to is not None:
+            n_max = max(n_max, int(pad_to))
+        if frozen is not None and initial is None:
+            raise ValueError("frozen jobs require an explicit initial "
+                             "assignment (greedy init would reassign them)")
+        mpts = _per_instance_mpt(machines_per_tier, B)
+        m_max = (max(c for c, _ in mpts), max(e for _, e in mpts))
+        if busy_until is None:
+            busy_until = [None] * B
+        if n_max == 0:
+            return np.zeros((B,)), [np.zeros((0,), np.int64) for _ in range(B)]
 
-    rel = np.zeros((B, n_max), np.float32)
-    w = np.zeros((B, n_max), np.float32)
-    proc = np.zeros((B, n_max, N_MACHINES), np.float32)
-    trans = np.zeros((B, n_max, N_MACHINES), np.float32)
-    movable = np.zeros((B, n_max), bool)
-    assign0 = np.full((B, n_max), 2, np.int32)  # phantoms pinned to device
-    busy_c = np.full((B, m_max[0]), np.inf, np.float32)
-    busy_e = np.full((B, m_max[1]), np.inf, np.float32)
-    for b, jobs in enumerate(batch_jobs):
-        nb = sizes[b]
-        bc, be = _normalize_busy(busy_until[b], mpts[b])
-        busy_c[b, :mpts[b][0]] = bc
-        busy_e[b, :mpts[b][1]] = be
-        rr, rt = rsv[b]
-        if nb:
-            rel[b, :nb], w[b, :nb], proc[b, :nb], trans[b, :nb] = \
-                _specs_to_np(jobs)
-            movable[b, :nb] = True
-            if frozen is not None and frozen[b] is not None:
-                fr = np.asarray(list(frozen[b]), bool)
-                if fr.shape != (nb,):
-                    raise ValueError(f"ward {b}: frozen mask has shape "
-                                     f"{fr.shape}, expected ({nb},)")
-                movable[b, :nb] &= ~fr
-            if initial is not None:
-                assign0[b, :nb] = list(initial[b])
-        if rt.shape[0]:
-            hi = nb + rt.shape[0]
-            rel[b, nb:hi] = rr[:, 0]
-            w[b, nb:hi] = rr[:, 1]
-            proc[b, nb:hi] = rr[:, 2:5]
-            trans[b, nb:hi] = rr[:, 5:8]
-            assign0[b, nb:hi] = rt
-    mov_idx, mov_ok = _movable_slots(movable, n_max)
-    if max_rounds is None:
-        max_rounds = 50
-    # static regime dispatch (DESIGN.md §12): movable-dominated batches
-    # (movable bucket at least half the padded rows) take the wide
-    # steepest-descent rounds; background-heavy batches take the
-    # width-1 movable-slot passes. Both sides of the threshold are a
-    # pure function of the batch's padded shape, so every ward of one
-    # call follows one regime and B = 1 replays it exactly.
-    mode = kernel_regime(mov_idx.shape[1], n_max)
-    assign, totals, _ = _tabu_run_batched(
-        assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
-        np.int32(max_rounds), busy_c, busy_e, objective,
-        greedy_init=initial is None, mode=mode)
-    assign = np.asarray(assign)
-    return (np.asarray(totals, np.float64),
-            [assign[b, :sizes[b]] for b in range(B)])
+        rel = np.zeros((B, n_max), np.float32)
+        w = np.zeros((B, n_max), np.float32)
+        proc = np.zeros((B, n_max, N_MACHINES), np.float32)
+        trans = np.zeros((B, n_max, N_MACHINES), np.float32)
+        movable = np.zeros((B, n_max), bool)
+        assign0 = np.full((B, n_max), 2, np.int32)  # phantoms pinned to device
+        busy_c = np.full((B, m_max[0]), np.inf, np.float32)
+        busy_e = np.full((B, m_max[1]), np.inf, np.float32)
+        for b, jobs in enumerate(batch_jobs):
+            nb = sizes[b]
+            bc, be = _normalize_busy(busy_until[b], mpts[b])
+            busy_c[b, :mpts[b][0]] = bc
+            busy_e[b, :mpts[b][1]] = be
+            rr, rt = rsv[b]
+            if nb:
+                rel[b, :nb], w[b, :nb], proc[b, :nb], trans[b, :nb] = \
+                    _specs_to_np(jobs)
+                movable[b, :nb] = True
+                if frozen is not None and frozen[b] is not None:
+                    fr = np.asarray(list(frozen[b]), bool)
+                    if fr.shape != (nb,):
+                        raise ValueError(f"ward {b}: frozen mask has shape "
+                                         f"{fr.shape}, expected ({nb},)")
+                    movable[b, :nb] &= ~fr
+                if initial is not None:
+                    assign0[b, :nb] = list(initial[b])
+            if rt.shape[0]:
+                hi = nb + rt.shape[0]
+                rel[b, nb:hi] = rr[:, 0]
+                w[b, nb:hi] = rr[:, 1]
+                proc[b, nb:hi] = rr[:, 2:5]
+                trans[b, nb:hi] = rr[:, 5:8]
+                assign0[b, nb:hi] = rt
+        mov_idx, mov_ok = _movable_slots(movable, n_max)
+        if max_rounds is None:
+            max_rounds = 50
+        # static regime dispatch (DESIGN.md §12): movable-dominated batches
+        # (movable bucket at least half the padded rows) take the wide
+        # steepest-descent rounds; background-heavy batches take the
+        # width-1 movable-slot passes. Both sides of the threshold are a
+        # pure function of the batch's padded shape, so every ward of one
+        # call follows one regime and B = 1 replays it exactly.
+        mode = kernel_regime(mov_idx.shape[1], n_max)
+        args = (assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
+                np.int32(max_rounds), busy_c, busy_e)
+    # what the dispatch moves and runs, all known on the host; counted
+    # only while a recorder is armed
+    counters = {} if spans.armed() is None else dict(
+        B=B, rows_real=sum(rows), rows_padded=B * n_max,
+        slots=int(mov_idx.shape[1]), regime=mode,
+        h2d_bytes=sum(int(a.nbytes) for a in args))
+    with spans.span("scheduler.dispatch", **counters):
+        assign, totals, _ = _tabu_run_batched(
+            *args, objective, greedy_init=initial is None, mode=mode)
+    with spans.span("scheduler.fetch"):
+        assign = np.asarray(assign)
+        totals = np.asarray(totals, np.float64)
+    return totals, [assign[b, :sizes[b]] for b in range(B)]
 
 
 def tabu_search_jax(jobs: Sequence[JobSpec],

@@ -43,8 +43,7 @@ from repro.metro.policies import (SHED, FleetPolicy, GreedyPolicy,
                                   SheddingPolicy, TabuPolicy, make_policy)
 from repro.metro.sanitizer import MetroSanitizer, SanitizerViolation
 from repro.metro.traces import SCENARIO_PACKS, Scenario, make_scenario
-from repro.metro.tracing import (TERMS, EngineProfile, MetroTrace,
-                                 MetroTracer, Span)
+from repro.metro.tracing import TERMS, MetroTrace, MetroTracer, Span
 
 __all__ = ["FailureEvent", "MetroEngine", "MetroResult", "NetworkEvent",
            "ScaleEvent", "SlowdownEvent", "simulate_metro", "MetroMetrics",
@@ -52,4 +51,4 @@ __all__ = ["FailureEvent", "MetroEngine", "MetroResult", "NetworkEvent",
            "HedgingPolicy", "Policy", "SheddingPolicy", "TabuPolicy",
            "make_policy", "MetroSanitizer", "SanitizerViolation",
            "SCENARIO_PACKS", "Scenario", "make_scenario",
-           "TERMS", "EngineProfile", "MetroTrace", "MetroTracer", "Span"]
+           "TERMS", "MetroTrace", "MetroTracer", "Span"]

@@ -66,6 +66,7 @@ fresh process with a fixed section order.
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import time
 from dataclasses import dataclass, replace
@@ -77,6 +78,7 @@ from repro.core.simulator import JobSpec, Schedule, ScheduledJob
 from repro.core.tiers import CC, ED, ES
 from repro.metro.metrics import MetroMetrics
 from repro.metro.policies import SHED, HedgeRequest, Policy, ReplanRequest
+from repro.utils import spans
 
 _INF = float("inf")
 # same-instant ordering: completions first (a machine freeing at t is
@@ -391,11 +393,10 @@ class MetroEngine:
         self._ran = False
         # read-only invariant observer, attached by run(sanitize=True)
         self._san = None
-        # read-only flight recorder / self-profiler, attached by
-        # run(trace=True) / run(profile=True) — both None when off, so
-        # the off path costs one attribute test per observation
+        # read-only flight recorder, attached by run(trace=True) — None
+        # when off, so the off path costs one attribute test per
+        # observation
         self._tracer = None
-        self._prof = None
         for b, trace in enumerate(self.jobs):
             for i, job in enumerate(trace):
                 self._push(job.release, _P_ARRIVE, ("arrive", b, i))
@@ -524,44 +525,36 @@ class MetroEngine:
         (arrival, plan time, ward, index) over the slot free times —
         `simulate`'s C5 semantics with machine identity. Started jobs are
         untouched (C2); re-timed jobs get fresh completion events."""
-        if self._prof is not None:
-            _r0 = time.perf_counter()          # reprolint: disable=R002
-        free = self._slot_frees(pool, now)
-        queue = []
-        for b, i, c, is_hedge in self._pool_entries(pool):
-            if c.start > now:
-                queue.append((max(now, c.arrival), c.planned_at, b, i,
-                              is_hedge))
-        queue.sort()
-        heap = list(zip(free, range(len(free))))
-        heapq.heapify(heap)
-        for arr, _, b, i, is_hedge in queue:
-            c = self.hedges[(b, i)] if is_hedge else self.commits[b][i]
-            avail, k = heapq.heappop(heap)
-            start = arr if arr > avail else avail
-            end = _finish_time(pool.slots[k].slowdowns, start,
-                               c.job.proc[pool.tier])
-            if end == _INF:                          # pragma: no cover
-                raise ValueError(f"{pool.tier} pool has no dispatchable "
-                                 f"machine for {c.job.name}")
-            heapq.heappush(heap, (end, k))
-            if (start, end, k) != (c.start, c.end, c.slot):
-                c.start, c.end, c.slot = start, end, k
-                kind = "hcomplete" if is_hedge else "complete"
-                self._push(end, _P_COMPLETE, (kind, b, i, end))
-                if not is_hedge:
-                    self._watchdog(b, i, c, now)
-        pool.reserved = sorted(f for f, _ in heap)
-        if self._prof is not None:
-            self._prof.replay += (
-                time.perf_counter() - _r0)     # reprolint: disable=R002
+        with spans.span("engine.replay"):
+            free = self._slot_frees(pool, now)
+            queue = []
+            for b, i, c, is_hedge in self._pool_entries(pool):
+                if c.start > now:
+                    queue.append((max(now, c.arrival), c.planned_at, b, i,
+                                  is_hedge))
+            queue.sort()
+            heap = list(zip(free, range(len(free))))
+            heapq.heapify(heap)
+            for arr, _, b, i, is_hedge in queue:
+                c = self.hedges[(b, i)] if is_hedge else self.commits[b][i]
+                avail, k = heapq.heappop(heap)
+                start = arr if arr > avail else avail
+                end = _finish_time(pool.slots[k].slowdowns, start,
+                                   c.job.proc[pool.tier])
+                if end == _INF:                      # pragma: no cover
+                    raise ValueError(f"{pool.tier} pool has no "
+                                     f"dispatchable machine for "
+                                     f"{c.job.name}")
+                heapq.heappush(heap, (end, k))
+                if (start, end, k) != (c.start, c.end, c.slot):
+                    c.start, c.end, c.slot = start, end, k
+                    kind = "hcomplete" if is_hedge else "complete"
+                    self._push(end, _P_COMPLETE, (kind, b, i, end))
+                    if not is_hedge:
+                        self._watchdog(b, i, c, now)
+            pool.reserved = sorted(f for f, _ in heap)
         if self._san is not None:
-            if self._prof is not None:
-                _s0 = time.perf_counter()      # reprolint: disable=R002
-                self._san.check_pool(pool, now)
-                self._prof.sanitize += (
-                    time.perf_counter() - _s0)  # reprolint: disable=R002
-            else:
+            with spans.span("engine.sanitize"):
                 self._san.check_pool(pool, now)
 
     def _replay(self, now: float, edge_wards: Sequence[int] | None = None,
@@ -601,8 +594,10 @@ class MetroEngine:
                 changed = True
         return replace(spec, trans=trans) if changed else spec
 
-    def _decide(self, wards: Sequence[int], now: float,
-                fresh: Mapping[int, Sequence[int]] = ()) -> None:
+    def _requests(self, wards: Sequence[int], now: float,
+                  fresh: Mapping[int, Sequence[int]]
+                  ) -> List[ReplanRequest]:
+        """One `ReplanRequest` per decided ward that has movable jobs."""
         fresh = dict(fresh or {})
         cloud_busy = self._busy_view(self.cloud, now)
         # every ward's unstarted cloud commitments, shifted to `now`:
@@ -648,14 +643,14 @@ class MetroEngine:
                                    ES: len(self.edges[b].slots)},
                 background=[spec for c, j, spec in cloud_queue
                             if c != b or j not in mov]))
+        return requests
+
+    def _decide(self, wards: Sequence[int], now: float,
+                fresh: Mapping[int, Sequence[int]] = ()) -> None:
+        with spans.span("engine.requests"):
+            requests = self._requests(wards, now, fresh)
         if requests:
-            if self._prof is not None:
-                _p0 = time.perf_counter()      # reprolint: disable=R002
-                decisions = self.policy.decide(requests, now)
-                self._prof.policy += (
-                    time.perf_counter() - _p0)  # reprolint: disable=R002
-                self._prof.decide_calls += 1
-            else:
+            with spans.span("policy.decide"):
                 decisions = self.policy.decide(requests, now)
             if len(decisions) != len(requests):
                 raise ValueError(f"policy returned {len(decisions)} plans "
@@ -960,12 +955,7 @@ class MetroEngine:
                       ES: list(self.edges[b].reserved)},
             machines_per_tier={CC: len(self.cloud.slots),
                                ES: len(self.edges[b].slots)})
-        if self._prof is not None:
-            _h0 = time.perf_counter()          # reprolint: disable=R002
-            t = self._hedge_fn(req, now)
-            self._prof.hedge_hook += (
-                time.perf_counter() - _h0)     # reprolint: disable=R002
-        else:
+        with spans.span("engine.hedge_hook"):
             t = self._hedge_fn(req, now)
         if t is None:
             return
@@ -1063,6 +1053,40 @@ class MetroEngine:
             self._decide(range(self.B), now)
 
     # ---------------------------------------------------------------- run
+    def _drain(self) -> None:
+        """Pop and handle events until the heap is empty; each handler
+        runs inside one `engine.event` span, from pop to commit."""
+        while self._heap:
+            t, prio, _, payload = heapq.heappop(self._heap)
+            if self._san is not None:
+                self._san.on_event(t, payload)
+            self._t_end = max(self._t_end, t)
+            self._events += 1
+            kind = payload[0]
+            with spans.span("engine.event", kind=kind, seq=self._events):
+                if kind == "complete":
+                    self._on_complete(t, *payload[1:])
+                elif kind == "hcomplete":
+                    self._on_hcomplete(t, *payload[1:])
+                elif kind == "arrive":
+                    self._on_arrive(t, *payload[1:])
+                elif kind == "retry":
+                    self._on_retry(t, *payload[1:])
+                elif kind == "fail":
+                    self._on_fail(t, payload[1])
+                elif kind == "slow":
+                    self._on_slow(t, payload[1])
+                elif kind == "slowend":
+                    self._on_slowend(t, *payload[1:])
+                elif kind == "scale":
+                    self._on_scale(t, payload[1])
+                elif kind == "net":
+                    self._on_net(t, *payload[1:])
+                elif kind == "hedge":
+                    self._on_hedge(t, *payload[1:])
+                else:
+                    self._on_recover(t, *payload[1:])
+
     def run(self, sanitize: bool = False, trace: bool = False,
             profile: bool = False) -> MetroResult:
         """Drain the event heap. ``sanitize=True`` attaches the
@@ -1071,10 +1095,12 @@ class MetroEngine:
         engine invariants I1–I7 and a `SanitizerViolation` is raised on
         the first breach. ``trace=True`` attaches the flight recorder
         (`MetroTracer`, DESIGN.md §15): per-job spans and deadline-miss
-        attribution land on ``MetroResult.trace``. ``profile=True`` arms
-        the self-profiler: wall-clock phase timers (replay / policy /
-        sanitizer / hedge hook / per-event-kind handlers) plus the
-        compiled-shape cache delta land on ``MetroResult.profile``.
+        attribution land on ``MetroResult.trace``. ``profile=True`` records
+        the run's host spans (`repro.utils.spans`: into the armed
+        recorder if one is, else into one armed for the run) and puts
+        their phase summary (`tracing.engine_profile`: replay / policy
+        / sanitizer / hedge hook / per-event-kind handlers) on
+        ``MetroResult.profile``.
         All three observers are read-only — they never mutate state,
         push events or touch the event log, so armed runs hash
         bit-identically to bare ones."""
@@ -1088,51 +1114,14 @@ class MetroEngine:
         if trace:
             from repro.metro.tracing import MetroTracer
             self._tracer = MetroTracer(self)
-        if profile:
-            from repro.core.scheduler import compiled_shape_stats
-            from repro.metro.tracing import EngineProfile
-            self._prof = EngineProfile(
-                shapes_before=compiled_shape_stats())
-        prof = self._prof
         # bench-timing block: measures wall-clock THROUGHPUT of the run;
         # simulated time lives only in the event heap
-        t0 = time.perf_counter()        # reprolint: disable=R002
-        while self._heap:
-            t, prio, _, payload = heapq.heappop(self._heap)
-            if self._san is not None:
-                self._san.on_event(t, payload)
-            self._t_end = max(self._t_end, t)
-            self._events += 1
-            kind = payload[0]
-            if prof is not None:
-                _h0 = time.perf_counter()      # reprolint: disable=R002
-            if kind == "complete":
-                self._on_complete(t, *payload[1:])
-            elif kind == "hcomplete":
-                self._on_hcomplete(t, *payload[1:])
-            elif kind == "arrive":
-                self._on_arrive(t, *payload[1:])
-            elif kind == "retry":
-                self._on_retry(t, *payload[1:])
-            elif kind == "fail":
-                self._on_fail(t, payload[1])
-            elif kind == "slow":
-                self._on_slow(t, payload[1])
-            elif kind == "slowend":
-                self._on_slowend(t, *payload[1:])
-            elif kind == "scale":
-                self._on_scale(t, payload[1])
-            elif kind == "net":
-                self._on_net(t, *payload[1:])
-            elif kind == "hedge":
-                self._on_hedge(t, *payload[1:])
-            else:
-                self._on_recover(t, *payload[1:])
-            if prof is not None:
-                prof.add_handler(
-                    kind,
-                    time.perf_counter() - _h0)  # reprolint: disable=R002
-        seconds = time.perf_counter() - t0   # reprolint: disable=R002
+        with (spans.recording() if profile
+              else contextlib.nullcontext()) as rec:
+            first = len(rec.spans) if rec is not None else 0
+            t0 = time.perf_counter()    # reprolint: disable=R002
+            self._drain()
+            seconds = time.perf_counter() - t0  # reprolint: disable=R002
 
         if self._san is not None:
             self._san.at_exit(self._t_end)
@@ -1161,11 +1150,10 @@ class MetroEngine:
         if self._tracer is not None:
             trace_obj = self._tracer.finish()
         prof_out = None
-        if prof is not None:
-            from repro.core.scheduler import compiled_shape_stats
-            prof.heap_pushes = self._seq
-            prof_out = prof.summary(seconds, self._events,
-                                    shapes_after=compiled_shape_stats())
+        if rec is not None:
+            from repro.metro.tracing import engine_profile
+            prof_out = engine_profile(rec.spans[first:], seconds,
+                                      self._events)
         return MetroResult(policy=getattr(self.policy, "name", "?"),
                            wards=wards, metrics=self.metrics,
                            utilization=self._utilization(),

@@ -1,5 +1,5 @@
 """Metro flight recorder (DESIGN.md §15): per-job span tracing,
-deadline-miss attribution, and engine self-profiling.
+deadline-miss attribution, and the engine self-profile's summary.
 
 The metrics layer (§10) reports *that* deadlines were missed; this module
 records *why*. A `MetroTracer` is a read-only observer the engine consults
@@ -568,50 +568,32 @@ class MetroTrace:
                          f"expected 'jsonl' or 'chrome'")
 
 
-class EngineProfile:
-    """Engine self-profiling accumulators (armed by
-    ``MetroEngine.run(profile=True)``): wall-clock phase timers for the
-    replay, policy calls, the sanitizer and the hedge hook, per-event-kind
-    handler times, and heap/bookkeeping residual. Pure measurement — the
-    profiler never influences event timing (simulated time lives in the
-    heap), so profiled runs stay bit-identical."""
+# engine phase spans -> the profile summary's keys (DESIGN.md §15)
+_PHASES = {"engine.replay": "replay", "policy.decide": "policy",
+           "engine.sanitize": "sanitize", "engine.hedge_hook": "hedge_hook"}
 
-    __slots__ = ("replay", "policy", "sanitize", "hedge_hook",
-                 "handlers", "heap_pushes", "decide_calls",
-                 "shapes_before")
 
-    def __init__(self, shapes_before: Optional[dict] = None):
-        self.replay = 0.0
-        self.policy = 0.0
-        self.sanitize = 0.0
-        self.hedge_hook = 0.0
-        self.handlers: Dict[str, float] = {}
-        self.heap_pushes = 0
-        self.decide_calls = 0
-        self.shapes_before = dict(shapes_before or {})
-
-    def add_handler(self, kind: str, dt: float) -> None:
-        self.handlers[kind] = self.handlers.get(kind, 0.0) + dt
-
-    def summary(self, seconds_total: float, events: int,
-                shapes_after: Optional[dict] = None) -> dict:
-        handled = sum(self.handlers.values())
-        out = {
-            "seconds_total": seconds_total,
-            "events": events,
-            "replay": self.replay,
-            "policy": self.policy,
-            "sanitize": self.sanitize,
-            "hedge_hook": self.hedge_hook,
-            "heap_and_dispatch": max(0.0, seconds_total - handled),
-            "handlers_by_kind": dict(sorted(self.handlers.items())),
-            "heap_pushes": self.heap_pushes,
-            "decide_calls": self.decide_calls,
-        }
-        if shapes_after is not None:
-            before = self.shapes_before
-            out["compiled_shapes"] = dict(shapes_after)
-            out["compiled_shapes_delta"] = {
-                k: shapes_after.get(k, 0) - before.get(k, 0)
-                for k in ("hits", "misses", "evictions")}
-        return out
+def engine_profile(spans, seconds_total: float, events: int) -> dict:
+    """The engine self-profile (``MetroEngine.run(profile=True)``) from
+    one run's recorded host spans (`repro.utils.spans`): seconds in the
+    replay, policy calls, the sanitizer and the hedge hook, handler
+    seconds per event kind (`engine.event` spans), the loop's own time
+    outside every handler, and the count of policy calls."""
+    out = {"seconds_total": seconds_total, "events": events}
+    out.update((key, 0.0) for key in _PHASES.values())
+    handlers: Dict[str, float] = {}
+    decide_calls = 0
+    for sp in spans:
+        if sp.t1 is None:
+            continue
+        if sp.name == "engine.event":
+            kind = sp.attrs["kind"]
+            handlers[kind] = handlers.get(kind, 0.0) + sp.seconds
+        elif sp.name in _PHASES:
+            out[_PHASES[sp.name]] += sp.seconds
+            decide_calls += sp.name == "policy.decide"
+    out["heap_and_dispatch"] = max(0.0,
+                                   seconds_total - sum(handlers.values()))
+    out["handlers_by_kind"] = dict(sorted(handlers.items()))
+    out["decide_calls"] = decide_calls
+    return out

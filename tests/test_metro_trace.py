@@ -294,13 +294,13 @@ def test_engine_profile_accounts_for_the_run(traced_tail):
     assert prof["events"] == traced_tail.summary()["events"]
     assert prof["seconds_total"] > 0.0
     assert prof["decide_calls"] > 0
-    assert prof["heap_pushes"] >= prof["events"]
     assert prof["handlers_by_kind"]
     busy = (prof["replay"] + prof["policy"] + prof["sanitize"]
             + prof["hedge_hook"])
     assert 0.0 <= busy <= prof["seconds_total"] * 1.05
-    assert set(prof["compiled_shapes_delta"]) == \
-        {"hits", "misses", "evictions"}
+    for removed in ("heap_pushes", "compiled_shapes",
+                    "compiled_shapes_delta", "shapes_before"):
+        assert removed not in prof
 
 
 # ------------------------------------------- windowed metrics final flush
