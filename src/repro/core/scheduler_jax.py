@@ -40,6 +40,7 @@ Python simulator's free-time heap. Queue order ties break by
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence, Tuple
 
 import jax
@@ -723,23 +724,97 @@ def _reservation_rows(resv):
             np.asarray(tiers, np.int32))
 
 
-def _movable_slots(movable: np.ndarray, n_max: int):
-    """Bucketed movable-slot index arrays for the batch (DESIGN.md §12):
-    S = the max per-instance movable count rounded up to a multiple of 16
+def _slot_bucket(smax: int, n_max: int) -> int:
+    """The movable-slot count S of a batch whose largest instance has
+    `smax` movable jobs (DESIGN.md §12): rounded up to a multiple of 16
     (capped at n_max), so the compiled (B, n, S) kernel shape stays
-    stable while reservation/background counts drift under metro load.
-    Returns (mov_idx (B, S) int32 job ids, mov_ok (B, S) bool — padding
-    slots point at job 0 and are masked +inf by the round)."""
-    B = movable.shape[0]
-    smax = int(movable.sum(axis=1).max()) if B else 0
-    S = min(n_max, ((max(smax, 1) + 15) // 16) * 16)
-    mov_idx = np.zeros((B, S), np.int32)
-    mov_ok = np.zeros((B, S), bool)
-    for b in range(B):
+    stable while reservation/background counts drift under metro load."""
+    return min(n_max, ((max(smax, 1) + 15) // 16) * 16)
+
+
+def _movable_slots(movable: np.ndarray, mov_idx: np.ndarray,
+                   mov_ok: np.ndarray) -> None:
+    """Fill the (B, S) movable-slot arrays from the (B, n) movable mask:
+    each ward's movable job ids as a prefix of its row, mov_ok 1 there;
+    padding slots point at job 0 with mov_ok 0 and are masked +inf by
+    the round."""
+    for b in range(movable.shape[0]):
         idx = np.flatnonzero(movable[b])
         mov_idx[b, :len(idx)] = idx
         mov_ok[b, :len(idx)] = True
-    return mov_idx, mov_ok
+
+
+# The packed calling convention of the device search (DESIGN.md §8):
+# `_tabu_run_batched`'s eleven inputs cross to the device as one flat
+# int32 buffer, fields in this order, float32 bit-cast ("f"), bools as
+# 0/1 ("b"), int32 as is ("i"); the result comes back as one array.
+_PACKED_FIELDS = (("assign0", "i"), ("rel", "f"), ("w", "f"),
+                  ("proc", "f"), ("trans", "f"), ("movable", "b"),
+                  ("mov_idx", "i"), ("mov_ok", "b"), ("max_rounds", "i"),
+                  ("busy_c", "f"), ("busy_e", "f"))
+
+
+@functools.lru_cache(maxsize=64)
+def _packed_fields(layout: Tuple[int, int, int, int, int]):
+    """((name, kind, shape, offset, size), ...) of each packed field for
+    the static layout (B, n_max, S, m_cloud, m_edge), and the buffer's
+    length. Cached: every search packs, and a run meets only the few
+    layouts it compiled."""
+    B, n, S, mc, me = layout
+    shapes = {"assign0": (B, n), "rel": (B, n), "w": (B, n),
+              "proc": (B, n, N_MACHINES), "trans": (B, n, N_MACHINES),
+              "movable": (B, n), "mov_idx": (B, S), "mov_ok": (B, S),
+              "max_rounds": (), "busy_c": (B, mc), "busy_e": (B, me)}
+    fields, off = [], 0
+    for name, kind in _PACKED_FIELDS:
+        size = math.prod(shapes[name])
+        fields.append((name, kind, shapes[name], off, size))
+        off += size
+    return tuple(fields), off
+
+
+def _field_views(buf: np.ndarray, layout):
+    """A writable host view of each field into the packed int32 buffer
+    (float32 fields as float32 views, the rest as int32), so filling the
+    fields packs them."""
+    views = {}
+    for name, kind, shape, off, size in _packed_fields(layout)[0]:
+        v = buf[off:off + size].reshape(shape)
+        views[name] = v.view(np.float32) if kind == "f" else v
+    return views
+
+
+def _unpack(buf, layout):
+    """`_tabu_run_batched`'s eleven inputs, in its argument order, from
+    the packed buffer, traced: static slices, float32 bit-cast back,
+    bools as != 0. Every value comes back bit for bit."""
+    fields, _ = _packed_fields(layout)
+    out = []
+    for _, kind, shape, off, size in fields:
+        x = buf[off:off + size].reshape(shape)
+        if kind == "f":
+            x = jax.lax.bitcast_convert_type(x, jnp.float32)
+        elif kind == "b":
+            x = x != 0
+        out.append(x)
+    return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnames=("layout", "objective",
+                                             "greedy_init", "mode"))
+def _tabu_run_packed(buf, layout, objective: str,
+                     greedy_init: bool = False, mode: str = "pass"):
+    """`_tabu_run_batched` across one buffer each way: the inputs from the
+    packed buffer (`_unpack`), the result as one (B, n_max + 1) int32
+    array, the assignment in the first n_max columns and the float32
+    objective bit-cast into the last. The round count stays on the
+    device."""
+    assign, totals, _ = _tabu_run_batched(
+        *_unpack(buf, layout), objective, greedy_init=greedy_init,
+        mode=mode)
+    return jnp.concatenate(
+        [assign, jax.lax.bitcast_convert_type(totals, jnp.int32)[:, None]],
+        axis=1)
 
 
 def _per_instance_mpt(machines_per_tier, B: int):
@@ -840,65 +915,70 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
             busy_until = [None] * B
         if n_max == 0:
             return np.zeros((B,)), [np.zeros((0,), np.int64) for _ in range(B)]
-
-        rel = np.zeros((B, n_max), np.float32)
-        w = np.zeros((B, n_max), np.float32)
-        proc = np.zeros((B, n_max, N_MACHINES), np.float32)
-        trans = np.zeros((B, n_max, N_MACHINES), np.float32)
-        movable = np.zeros((B, n_max), bool)
-        assign0 = np.full((B, n_max), 2, np.int32)  # phantoms pinned to device
-        busy_c = np.full((B, m_max[0]), np.inf, np.float32)
-        busy_e = np.full((B, m_max[1]), np.inf, np.float32)
+        # the frozen masks first: they fix the movable-slot bucket S, and
+        # so the buffer's layout
+        frozen_masks = [None] * B
+        if frozen is not None:
+            for b in range(B):
+                if sizes[b] and frozen[b] is not None:
+                    fr = np.asarray(list(frozen[b]), bool)
+                    if fr.shape != (sizes[b],):
+                        raise ValueError(
+                            f"ward {b}: frozen mask has shape {fr.shape}, "
+                            f"expected ({sizes[b]},)")
+                    frozen_masks[b] = fr
+        smax = max(nb - (0 if fr is None else int(fr.sum()))
+                   for nb, fr in zip(sizes, frozen_masks))
+        layout = (B, n_max, _slot_bucket(smax, n_max), *m_max)
+        # every field is a view into the one buffer the dispatch moves
+        buf = np.zeros(_packed_fields(layout)[1], np.int32)
+        f = _field_views(buf, layout)
+        f["assign0"][:] = 2                         # phantoms pinned to device
+        f["busy_c"][:] = np.inf
+        f["busy_e"][:] = np.inf
         for b, jobs in enumerate(batch_jobs):
             nb = sizes[b]
             bc, be = _normalize_busy(busy_until[b], mpts[b])
-            busy_c[b, :mpts[b][0]] = bc
-            busy_e[b, :mpts[b][1]] = be
+            f["busy_c"][b, :mpts[b][0]] = bc
+            f["busy_e"][b, :mpts[b][1]] = be
             rr, rt = rsv[b]
             if nb:
-                rel[b, :nb], w[b, :nb], proc[b, :nb], trans[b, :nb] = \
-                    _specs_to_np(jobs)
-                movable[b, :nb] = True
-                if frozen is not None and frozen[b] is not None:
-                    fr = np.asarray(list(frozen[b]), bool)
-                    if fr.shape != (nb,):
-                        raise ValueError(f"ward {b}: frozen mask has shape "
-                                         f"{fr.shape}, expected ({nb},)")
-                    movable[b, :nb] &= ~fr
+                (f["rel"][b, :nb], f["w"][b, :nb], f["proc"][b, :nb],
+                 f["trans"][b, :nb]) = _specs_to_np(jobs)
+                f["movable"][b, :nb] = True if frozen_masks[b] is None \
+                    else ~frozen_masks[b]
                 if initial is not None:
-                    assign0[b, :nb] = list(initial[b])
+                    f["assign0"][b, :nb] = list(initial[b])
             if rt.shape[0]:
                 hi = nb + rt.shape[0]
-                rel[b, nb:hi] = rr[:, 0]
-                w[b, nb:hi] = rr[:, 1]
-                proc[b, nb:hi] = rr[:, 2:5]
-                trans[b, nb:hi] = rr[:, 5:8]
-                assign0[b, nb:hi] = rt
-        mov_idx, mov_ok = _movable_slots(movable, n_max)
-        if max_rounds is None:
-            max_rounds = 50
+                f["rel"][b, nb:hi] = rr[:, 0]
+                f["w"][b, nb:hi] = rr[:, 1]
+                f["proc"][b, nb:hi] = rr[:, 2:5]
+                f["trans"][b, nb:hi] = rr[:, 5:8]
+                f["assign0"][b, nb:hi] = rt
+        _movable_slots(f["movable"], f["mov_idx"], f["mov_ok"])
+        f["max_rounds"][...] = 50 if max_rounds is None else max_rounds
         # static regime dispatch (DESIGN.md §12): movable-dominated batches
         # (movable bucket at least half the padded rows) take the wide
         # steepest-descent rounds; background-heavy batches take the
         # width-1 movable-slot passes. Both sides of the threshold are a
         # pure function of the batch's padded shape, so every ward of one
         # call follows one regime and B = 1 replays it exactly.
-        mode = kernel_regime(mov_idx.shape[1], n_max)
-        args = (assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
-                np.int32(max_rounds), busy_c, busy_e)
+        mode = kernel_regime(layout[2], n_max)
     # what the dispatch moves and runs, all known on the host; counted
     # only while a recorder is armed
-    counters = {} if spans.armed() is None else dict(
-        B=B, rows_real=sum(rows), rows_padded=B * n_max,
-        slots=int(mov_idx.shape[1]), regime=mode,
-        h2d_bytes=sum(int(a.nbytes) for a in args))
+    armed = spans.armed() is not None
+    counters = {} if not armed else dict(
+        B=B, rows_real=sum(rows), rows_padded=B * n_max, slots=layout[2],
+        regime=mode, h2d_arrays=1, h2d_bytes=int(buf.nbytes))
     with spans.span("scheduler.dispatch", **counters):
-        assign, totals, _ = _tabu_run_batched(
-            *args, objective, greedy_init=initial is None, mode=mode)
-    with spans.span("scheduler.fetch"):
-        assign = np.asarray(assign)
-        totals = np.asarray(totals, np.float64)
-    return totals, [assign[b, :sizes[b]] for b in range(B)]
+        out = _tabu_run_packed(buf, layout, objective,
+                               greedy_init=initial is None, mode=mode)
+    with spans.span("scheduler.fetch", **({"d2h_arrays": 1} if armed
+                                          else {})):
+        out = np.asarray(out)
+    totals = out[:, n_max].view(np.float32).astype(np.float64)
+    return totals, [out[b, :sizes[b]] for b in range(B)]
 
 
 def tabu_search_jax(jobs: Sequence[JobSpec],

@@ -18,10 +18,13 @@ SCRIPT = ROOT / "chip_smoke.py"
 @pytest.fixture(autouse=True, scope="module")
 def _isolate_compiled_shapes():
     """The phases force the JAX search (jax_threshold=0), which records
-    bucketed shapes in the module-global fast-path set — restore it so
-    later test modules keep their CPU default dispatch."""
+    bucketed shapes in the module-global fast-path set — start from the
+    empty set a fresh process has, whatever ran before in this worker,
+    and restore it so later test modules keep their CPU default
+    dispatch."""
     saved = set(scheduler._COMPILED_SHAPES)
     stats = dict(scheduler._SHAPE_STATS)
+    scheduler._COMPILED_SHAPES.clear()
     yield
     scheduler._COMPILED_SHAPES.clear()
     scheduler._COMPILED_SHAPES.update(saved)

@@ -8,7 +8,7 @@ from dataclasses import replace
 
 import pytest
 
-from repro.core import problems
+from repro.core import problems, scheduler
 from repro.metro import TabuPolicy, simulate_metro
 from repro.utils import spans
 
@@ -26,6 +26,20 @@ def table6_stream(periods=2, period=25.0):
 def replay(**kw):
     return simulate_metro(table6_stream(), TabuPolicy(jax_threshold=0),
                           machines_per_tier=MPT, **kw)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _isolate_compiled_shapes():
+    """Every replay here forces the JAX search (jax_threshold=0), which
+    records its bucketed shape in the module-global fast-path set;
+    restore the set so later test modules keep their CPU default
+    dispatch."""
+    saved = set(scheduler._COMPILED_SHAPES)
+    stats = dict(scheduler._SHAPE_STATS)
+    yield
+    scheduler._COMPILED_SHAPES.clear()
+    scheduler._COMPILED_SHAPES.update(saved)
+    scheduler._SHAPE_STATS.update(stats)
 
 
 @pytest.fixture(scope="module")
@@ -97,6 +111,20 @@ def test_every_decision_event_holds_the_search_phases(recorded):
     assert 0 < dispatch["rows_real"] <= dispatch["rows_padded"]
     assert dispatch["slots"] >= len(decides)
     assert dispatch["h2d_bytes"] > 0
+
+
+def test_every_search_moves_one_array_each_way(recorded):
+    # the packed boundary (DESIGN.md §8): one buffer in, one array out,
+    # on every device search of the run
+    res, rec = recorded
+    dispatch = [s for s in rec.spans if s.name == "scheduler.dispatch"]
+    fetch = [s for s in rec.spans if s.name == "scheduler.fetch"]
+    assert dispatch and len(fetch) == len(dispatch)
+    assert all(s.attrs["h2d_arrays"] == 1 for s in dispatch)
+    assert all(s.attrs["d2h_arrays"] == 1 for s in fetch)
+    assert all(s.attrs["h2d_bytes"] > 0 for s in dispatch)
+    assert zlib.crc32(repr(res.event_log).encode()) == \
+        zlib.crc32(repr(replay().event_log).encode())
 
 
 def test_spans_nest_in_time(recorded):

@@ -13,7 +13,7 @@ import pytest
 from prop import sweep
 from repro.core import online, scheduler, scheduler_jax
 from repro.core.problems import ward_batch
-from repro.core.simulator import MACHINES, JobSpec, simulate
+from repro.core.simulator import MACHINES, JobSpec, Reservation, simulate
 from repro.core.tiers import CC, ED, ES
 
 
@@ -165,6 +165,120 @@ class TestPhantomPadding:
             [[], _random_jobs(np.random.default_rng(0), 5)])
         assert vals[0] == 0.0 and len(assigns[0]) == 0
         assert len(assigns[1]) == 5
+
+
+def _packed_case(size, regime):
+    """(batch, tabu_search_batched kwargs) for one ward alone ("B1") or a
+    mixed-size three-ward batch ("B3") with reservations, frozen jobs and
+    phantom machines, padded (pad_to) into the `regime` it names."""
+    rng = np.random.default_rng(70)
+    if size == "B1":
+        batch = [_random_jobs(rng, 10)]
+        kw = dict(machines_per_tier=(1, 1), busy_until=[([3.0], [])],
+                  max_rounds=4)
+    else:
+        batch = [_random_jobs(rng, n) for n in (4, 11, 7)]
+        resv = {CC: [Reservation(arrival=12.0, proc=9.0, release=2.0,
+                                 weight=2.0)],
+                ES: [Reservation(arrival=4.0, proc=6.0, release=4.0)]}
+        kw = dict(initial=[[int(x) for x in rng.integers(0, 3, len(j))]
+                           for j in batch],
+                  machines_per_tier=[(1, 1), (2, 3), (1, 2)],
+                  busy_until=[None, ([5.0, 17.0], [0.0, 3.0]), ([8.0], [])],
+                  frozen=[None, [k % 3 == 0 for k in range(11)], None],
+                  reserved=[None, None, resv])
+    if regime == "pass":
+        kw["pad_to"] = 48        # 16 movable slots in 48 rows
+    return batch, kw
+
+
+def _captured_search(monkeypatch, batch, kw):
+    """Run tabu_search_batched, keeping the packed buffer, its layout and
+    the static arguments the dispatch was handed."""
+    seen = {}
+    packed = scheduler_jax._tabu_run_packed
+
+    def spy(buf, layout, objective, **static):
+        seen.update(buf=buf.copy(), layout=layout, objective=objective,
+                    **static)
+        return packed(buf, layout, objective, **static)
+    monkeypatch.setattr(scheduler_jax, "_tabu_run_packed", spy)
+    return scheduler_jax.tabu_search_batched(batch, **kw), seen
+
+
+def _host_inputs(seen):
+    """`_tabu_run_batched`'s eleven host arguments read off the buffer:
+    float32 and int32 fields as they are, bools from 0/1."""
+    views = scheduler_jax._field_views(seen["buf"], seen["layout"])
+    kinds = dict(scheduler_jax._PACKED_FIELDS)
+    out = {name: v != 0 if kinds[name] == "b" else v
+           for name, v in views.items()}
+    out["max_rounds"] = np.int32(out["max_rounds"])
+    return out
+
+
+@pytest.mark.parametrize("size", ["B1", "B3"])
+@pytest.mark.parametrize("regime", ["round", "pass"])
+class TestPackedBoundary:
+    """The search crosses to the device as one int32 buffer and back as
+    one array (DESIGN.md §8 "The packed boundary")."""
+
+    def test_fields_round_trip_bit_for_bit(self, monkeypatch, size,
+                                           regime):
+        import jax
+        batch, kw = _packed_case(size, regime)
+        _, seen = _captured_search(monkeypatch, batch, kw)
+        assert seen["mode"] == regime
+        host = _host_inputs(seen)
+        got = jax.jit(scheduler_jax._unpack, static_argnums=1)(
+            seen["buf"], seen["layout"])
+        kinds = dict(scheduler_jax._PACKED_FIELDS)
+        assert len(got) == len(kinds) == 11
+        for (name, kind), g in zip(scheduler_jax._PACKED_FIELDS, got):
+            g, want = np.asarray(g), host[name]
+            assert g.shape == want.shape, name
+            assert g.dtype == {"f": np.float32, "b": np.bool_,
+                               "i": np.int32}[kind], name
+            if kind == "f":                     # bits, +inf and -0.0 too
+                assert np.array_equal(g.view(np.int32),
+                                      want.view(np.int32)), name
+            else:
+                assert np.array_equal(g, want), name
+        # what the fields hold: the jobs, the movable slots, the rounds
+        # and +inf-busy phantom machines
+        views = scheduler_jax._field_views(seen["buf"], seen["layout"])
+        assert set(np.unique(views["movable"])) <= {0, 1}
+        assert set(np.unique(views["mov_ok"])) <= {0, 1}
+        for b, jobs in enumerate(batch):
+            assert host["rel"][b, :len(jobs)].tolist() == \
+                [j.release for j in jobs]
+            assert host["mov_ok"][b].sum() == host["movable"][b].sum()
+        assert host["max_rounds"] == kw.get("max_rounds", 50)
+        mpts = kw["machines_per_tier"]
+        mpts = [mpts] * len(batch) if len(mpts) == 2 and \
+            isinstance(mpts[0], int) else mpts
+        for b, (mc, me) in enumerate(mpts):
+            assert np.isposinf(host["busy_c"][b, mc:]).all()
+            assert np.isposinf(host["busy_e"][b, me:]).all()
+        if size == "B3":
+            assert np.isposinf(host["busy_c"]).any()
+            assert not host["movable"][1, 0] and host["movable"][1, 1]
+
+    def test_search_matches_the_unpacked_kernel(self, monkeypatch, size,
+                                                regime):
+        batch, kw = _packed_case(size, regime)
+        (vals, assigns), seen = _captured_search(monkeypatch, batch, kw)
+        host = _host_inputs(seen)
+        assign, totals, _ = scheduler_jax._tabu_run_batched(
+            *(host[name] for name, _ in scheduler_jax._PACKED_FIELDS),
+            seen["objective"], greedy_init=seen["greedy_init"],
+            mode=seen["mode"])
+        assert np.array_equal(vals, np.asarray(totals, np.float64))
+        assert vals.dtype == np.float64
+        for b, jobs in enumerate(batch):
+            assert assigns[b].dtype == np.int32
+            assert np.array_equal(assigns[b],
+                                  np.asarray(assign)[b, :len(jobs)])
 
 
 class TestSearchBatchedDispatch:
