@@ -970,6 +970,7 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
     armed = spans.armed() is not None
     counters = {} if not armed else dict(
         B=B, rows_real=sum(rows), rows_padded=B * n_max, slots=layout[2],
+        reserved_rows=sum(rr.shape[0] for rr, _ in rsv),
         regime=mode, h2d_arrays=1, h2d_bytes=int(buf.nbytes))
     with spans.span("scheduler.dispatch", **counters):
         out = _tabu_run_packed(buf, layout, objective,

@@ -596,8 +596,9 @@ class MetroEngine:
 
     def _requests(self, wards: Sequence[int], now: float,
                   fresh: Mapping[int, Sequence[int]]
-                  ) -> List[ReplanRequest]:
-        """One `ReplanRequest` per decided ward that has movable jobs."""
+                  ) -> Tuple[List[ReplanRequest], int]:
+        """One `ReplanRequest` per decided ward that has movable jobs,
+        and how many cloud-queue entries were gathered for them."""
         fresh = dict(fresh or {})
         cloud_busy = self._busy_view(self.cloud, now)
         # every ward's unstarted cloud commitments, shifted to `now`:
@@ -631,7 +632,7 @@ class MetroEngine:
             new = set(fresh.get(b, ()))
             mov = set(movable)
             requests.append(ReplanRequest(
-                ward=b, movable=movable, shifted=shifted,
+                ward=b, movable=list(movable), shifted=shifted,
                 current=[None if self.commits[b][i] is None
                          else self.commits[b][i].machine for i in movable],
                 fresh=[p for p, i in enumerate(movable) if i in new],
@@ -643,12 +644,13 @@ class MetroEngine:
                                    ES: len(self.edges[b].slots)},
                 background=[spec for c, j, spec in cloud_queue
                             if c != b or j not in mov]))
-        return requests
+        return requests, len(cloud_queue)
 
     def _decide(self, wards: Sequence[int], now: float,
                 fresh: Mapping[int, Sequence[int]] = ()) -> None:
-        with spans.span("engine.requests"):
-            requests = self._requests(wards, now, fresh)
+        with spans.span("engine.requests") as sp:
+            requests, queued = self._requests(wards, now, fresh)
+            sp.set(wards=len(requests), background=queued)
         if requests:
             with spans.span("policy.decide"):
                 decisions = self.policy.decide(requests, now)

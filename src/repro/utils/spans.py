@@ -5,7 +5,8 @@ self-profile").
     with spans.span("scheduler.pack"):
         ...
 
-marks one piece of host work. While no recorder is armed, `span` hands
+marks one piece of host work; `set(**attrs)` on the entered span adds
+counts known only at its end. While no recorder is armed, `span` hands
 back one shared object whose enter and exit do nothing: no clock read,
 no allocation. `recording()` arms a recorder for a block:
 
@@ -51,6 +52,9 @@ class _Off:
         return self
 
     def __exit__(self, *exc):
+        return None
+
+    def set(self, **attrs):
         return None
 
 
@@ -114,6 +118,10 @@ class _Open:
         rec._open.pop()
         rec._view = None
         return None
+
+    def set(self, **attrs):
+        """Add attrs known only once the span's work is done."""
+        self._attrs.update(attrs)
 
 
 class Recorder:

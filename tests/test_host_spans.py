@@ -77,6 +77,17 @@ def test_recording_arms_once_and_disarms():
     assert [s.name for s in outer.spans] == ["a"]
 
 
+def test_set_adds_attrs_to_an_open_span():
+    with spans.span("off") as off:
+        off.set(n=1)                  # the shared no-op while unarmed
+    with spans.recording() as rec:
+        with spans.span("work", seq=4) as sp:
+            sp.set(done=3, kind="x")
+    (s,) = rec.spans
+    assert s.attrs == {"seq": 4, "done": 3, "kind": "x"}
+    assert rec.summary()["work"]["done"] == 3
+
+
 def test_every_decision_event_holds_the_search_phases(recorded):
     res, rec = recorded
     decides = [k for k, s in enumerate(rec.spans)

@@ -115,3 +115,16 @@ def test_packed_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows,
     assert out.shape == (batch, rows + 1) and out.dtype == jnp.int32
     assert scheduler_jax.kernel_regime(slots, rows) == mode
     assert "while" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode,rows", [("round", 32), ("pass", 48)])
+def test_pooled_cloud_searches_compile_for_v5e(one_chip, mode, rows):
+    # metro15icu.replan's searches: one ward's jobs and the other wards'
+    # reservations in 32 or 48 rows, 16 movable slots, 15 cloud machines
+    layout = (1, rows, 16, 15, 1)
+    _, size = scheduler_jax._packed_fields(layout)
+    buf = _spec((size,), jnp.int32, one_chip)
+    compiled = scheduler_jax._tabu_run_packed.lower(
+        buf, layout, "weighted", mode=mode).compile()
+    assert scheduler_jax.kernel_regime(16, rows) == mode
+    assert "while" in compiled.as_text()
