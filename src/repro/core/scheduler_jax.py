@@ -571,50 +571,12 @@ def _run_rounds(assign0, mov_idx, mov_ok, tc, dev, oi, max_moves, binds):
     return assign, totals, rounds
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("objective", "greedy_init", "mode"))
-def _tabu_run_batched(assign0, rel, w, proc, trans, movable, mov_idx,
-                      mov_ok, max_rounds, busy_c, busy_e, objective: str,
-                      greedy_init: bool = False, mode: str = "pass"):
-    """Algorithm-2 search for B instances at once, entirely on-device,
-    in one of two shape-dispatched regimes (DESIGN.md §12):
-
-    mode="pass" — the mostly-background regime (movable slots are a
-    small fraction of the padded rows). Each while_loop iteration is
-    one PASS over the movable slots; per slot the job's 3 destination
-    moves are delta-evaluated exactly against the CURRENT assignment (a
-    width-1 toggle carry) and a strictly improving best move commits
-    immediately, exactly like the incremental Python tabu round. The
-    toggle scan is carry-bandwidth-bound, so S cheap width-1 evals that
-    can each commit a move beat one width-S eval that commits one —
-    the steepest-descent rounds spent ~95% of mostly-converged fleet
-    sweeps re-pricing unchanged candidates.
-
-    mode="round" — the movable-dominated regime. One steepest-descent
-    round per while_loop iteration: all S toggles priced in one wide
-    carry, accept each instance's best strictly improving move (plus a
-    second, exactly-composing move on the other shared tier when one
-    improves). At small row counts the per-eval dispatch floor — not
-    carry width — dominates, so one wide eval per accepted move beats
-    S narrow evals per pass; `max_rounds` passes translate to a
-    `max_rounds * S` move budget.
-
-    Both regimes share the tier/device precomputation, per-instance
-    convergence flags (a converged ward idles while stragglers keep
-    searching), and drift-free values: the incumbent objective is
-    re-derived from fresh per-tier stats at every evaluation, so a
-    converged ward's reported value is a fresh full evaluation.
-    Machine counts are carried by the busy vector shapes (phantom
-    machines = +inf), so changing fleet sizes does not retrace beyond
-    the new shapes. max_rounds counts passes (the Python search's
-    max_count)."""
-    oi = _OBJ_IDX[objective]
-    B, n = assign0.shape
-    if greedy_init:
-        # greedy init is only reachable when every non-phantom job is
-        # movable (frozen jobs require an explicit initial assignment)
-        assign0 = _greedy_assign_batched(rel, w, proc, trans, movable,
-                                         busy_c, busy_e)
+def _kernel_consts(rel, w, proc, trans, busy_c, busy_e):
+    """The assignment-independent constants every evaluation of a search
+    reads: per shared tier the queue order and the job constants in it,
+    stacked (B, 2, n), with the (B, 2, m) machine free times (the smaller
+    tier padded with +inf phantom machines); and the private device
+    tier's per-job completion and response constants."""
     m_mm = max(busy_c.shape[1], busy_e.shape[1])
     busy_T = jnp.stack([
         jnp.pad(busy_c, ((0, 0), (0, m_mm - busy_c.shape[1])),
@@ -639,7 +601,175 @@ def _tabu_run_batched(assign0, rel, w, proc, trans, movable, mov_idx,
     dev_end = rel + trans[:, :, 2] + proc[:, :, 2]
     dev = {"end": dev_end, "resp": dev_end - rel,
            "wresp": w * (dev_end - rel)}
+    return tc, dev
 
+
+def _pass_walk(assign, active, mov_idx, mov_ok, tc, dev, oi: int):
+    """One mode="pass" pass for a batch of several wards: S width-1
+    evaluations in slot order, each pricing one slot's 3 moves against
+    the assignment as of that slot and committing a strictly improving
+    best move at once. Returns (assign, value, changed, evaluations)."""
+    B, S = mov_idx.shape
+    binds = jnp.arange(B)
+
+    def slot(carry, s):
+        assign, total, changed = carry
+        k = jnp.take(mov_idx, s, axis=1)            # (B,) job id
+        ok = jnp.take(mov_ok, s, axis=1) & active
+        tot, vals = _round_batched(assign, k[:, None], ok[:, None],
+                                   tc, dev, oi)
+        flat = vals[:, 0, :]                        # (B, 3)
+        m1 = jnp.argmin(flat, axis=1)
+        v1 = jnp.take_along_axis(flat, m1[:, None], axis=1)[:, 0]
+        improved = v1 < tot         # +inf masks no-ops and ~ok slots
+        assign = assign.at[binds, k].set(
+            jnp.where(improved, m1.astype(assign.dtype), assign[binds, k]))
+        # the carried value is the FRESH per-tier evaluation of the
+        # incumbent whenever the slot rejects its moves — so a converged
+        # ward (a full pass of rejections) always reports its final
+        # assignment's exact score; only a max_rounds cap can surface a
+        # (one-composition) delta-assembled value
+        total = jnp.where(improved, v1, tot)
+        return (assign, total, changed | improved), None
+
+    (assign, total, changed), _ = jax.lax.scan(
+        slot, (assign, jnp.full((B,), jnp.inf), jnp.zeros((B,), bool)),
+        jnp.arange(S))
+    return assign, total, changed, jnp.int32(S)
+
+
+def _pass_replay(assign, active, mov_idx, mov_ok, tc, dev, oi: int):
+    """One mode="pass" pass for a single ward (the kernel takes it at
+    B = 1; the code holds for any B): the same accept-as-you-go walk as
+    `_pass_walk`, replayed with one wide evaluation per accepted move.
+    Each instance carries a slot cursor; an evaluation prices every slot
+    at or past it against the current assignment, the first slot whose
+    best move strictly improves commits and the cursor moves past it,
+    and a cursor that finds none ends the instance's pass. Returns
+    (assign, value, changed, evaluations)."""
+    B, S = mov_idx.shape
+    binds = jnp.arange(B)
+    slots = jnp.arange(S)
+
+    def more(carry):
+        return jnp.any(carry[2] < S)
+
+    def step(carry):
+        assign, total, cur, changed, evals = carry
+        # the slots a width-1 walk would visit before its next accept all
+        # see exactly this assignment
+        tot, vals = _round_batched(
+            assign, mov_idx, mov_ok & active[:, None]
+            & (slots >= cur[:, None]), tc, dev, oi)
+        m1 = jnp.argmin(vals, axis=2)                       # (B, S)
+        v1 = jnp.take_along_axis(vals, m1[..., None], axis=2)[..., 0]
+        better = v1 < tot[:, None]          # +inf masks no-ops, ~ok slots
+        improved = jnp.any(better, axis=1)
+        s1 = jnp.argmax(better, axis=1)                     # the first
+        k = mov_idx[binds, s1]
+        assign = assign.at[binds, k].set(
+            jnp.where(improved, m1[binds, s1].astype(assign.dtype),
+                      assign[binds, k]))
+        # a ward whose cursor reached S keeps its pass's value; the others
+        # take the walk's: the accepted move's, or the FRESH incumbent when
+        # no slot past the cursor improves
+        total = jnp.where(cur < S,
+                          jnp.where(improved, v1[binds, s1], tot), total)
+        cur = jnp.where(improved, s1 + 1, S)
+        return assign, total, cur, changed | improved, evals + 1
+
+    assign, total, _, changed, evals = jax.lax.while_loop(
+        more, step, (assign, jnp.full((B,), jnp.inf),
+                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool),
+                     jnp.int32(0)))
+    return assign, total, changed, evals
+
+
+def _run_passes(assign0, mov_idx, mov_ok, tc, dev, oi, max_rounds,
+                one_pass):
+    """mode="pass" outer loop (see `_tabu_run_batched`): `one_pass`
+    (`_pass_walk` or `_pass_replay`) per while_loop iteration until no
+    ward changed or `max_rounds` passes ran. Returns (assign, totals,
+    rounds, evaluations)."""
+    B = assign0.shape[0]
+
+    def cond(state):
+        _, _, rnd, active, _ = state
+        return jnp.any(active) & (rnd < max_rounds)
+
+    def body(state):
+        assign, _, rnd, active, evals = state
+        assign, total, changed, ran = one_pass(assign, active, mov_idx,
+                                               mov_ok, tc, dev, oi)
+        return assign, total, rnd + 1, changed, evals + ran
+
+    state = (assign0, jnp.full((B,), jnp.inf), jnp.int32(0),
+             jnp.ones((B,), bool), jnp.int32(0))
+    assign, totals, rounds, _, evals = jax.lax.while_loop(cond, body, state)
+    # max_rounds == 0 (greedy probe): the loop never evaluated anything
+    totals = jax.lax.cond(
+        rounds == 0,
+        lambda args: _round_batched(args[0], mov_idx, mov_ok, tc, dev,
+                                    oi)[0],
+        lambda args: args[1], (assign, totals))
+    return assign, totals, rounds, evals + (rounds == 0)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("objective", "greedy_init", "mode"))
+def _tabu_run_batched(assign0, rel, w, proc, trans, movable, mov_idx,
+                      mov_ok, max_rounds, busy_c, busy_e, objective: str,
+                      greedy_init: bool = False, mode: str = "pass"):
+    """Algorithm-2 search for B instances at once, entirely on-device,
+    in one of two shape-dispatched regimes (DESIGN.md §12):
+
+    mode="pass" — the mostly-background regime (movable slots are a
+    small fraction of the padded rows). Each while_loop iteration is
+    one PASS over the movable slots in slot order, accepting as it goes
+    like the incremental Python tabu round: a slot's best move (argmin
+    over the 3 machines) commits when it strictly beats the assignment
+    as of that slot. A single ward (B = 1: every replan) replays that
+    first-improvement walk with one wide evaluation per accepted move
+    plus one per pass (`_pass_replay`): every slot the walk would price
+    before its next accept sees the same assignment, and per toggle
+    column the tier arithmetic is elementwise-identical at any width, so
+    assignments and values come out bit for bit as the walk's. On the
+    chip an evaluation costs its count of sequential ops, not its carry
+    width (a (1, 2, S, m) carry is a fraction of one vreg), so skipping
+    the evaluations whose answer is already known is the saving. A
+    batch of several wards keeps the walk of S width-1 evaluations
+    (`_pass_walk`): fleet sweeps (S ~ 112 slots over ~3,100 rows) run it
+    on the CPU too, where a wide evaluation's carry costs in proportion
+    to its width and the replay ran the benchmark fleet 3.5x slower.
+
+    mode="round" — the movable-dominated regime. One steepest-descent
+    round per while_loop iteration: all S toggles priced in one wide
+    carry, accept each instance's best strictly improving move (plus a
+    second, exactly-composing move on the other shared tier when one
+    improves). At small row counts the per-eval dispatch floor — not
+    carry width — dominates, so one wide eval per accepted move beats
+    S narrow evals per pass; `max_rounds` passes translate to a
+    `max_rounds * S` move budget.
+
+    Both regimes share the tier/device precomputation, per-instance
+    convergence flags (a converged ward idles while stragglers keep
+    searching), and drift-free values: the incumbent objective is
+    re-derived from fresh per-tier stats at every evaluation, so a
+    converged ward's reported value is a fresh full evaluation.
+    Machine counts are carried by the busy vector shapes (phantom
+    machines = +inf), so changing fleet sizes does not retrace beyond
+    the new shapes. max_rounds counts passes (the Python search's
+    max_count). Returns (assign, totals, rounds, evaluations): the last
+    is the number of tier evaluations the batch ran, the greedy probe's
+    included."""
+    oi = _OBJ_IDX[objective]
+    B = assign0.shape[0]
+    if greedy_init:
+        # greedy init is only reachable when every non-phantom job is
+        # movable (frozen jobs require an explicit initial assignment)
+        assign0 = _greedy_assign_batched(rel, w, proc, trans, movable,
+                                         busy_c, busy_e)
+    tc, dev = _kernel_consts(rel, w, proc, trans, busy_c, busy_e)
     binds = jnp.arange(B)
     S = mov_idx.shape[1]
     # real (non-padding) slots are a per-ward PREFIX of mov_idx
@@ -647,54 +777,12 @@ def _tabu_run_batched(assign0, rel, w, proc, trans, movable, mov_idx,
     # same job for a ward no matter how much batch padding it rides with
     # (the batched==solo parity suite pins this)
     if mode == "round":
-        return _run_rounds(assign0, mov_idx, mov_ok, tc, dev, oi,
-                           max_rounds * jnp.int32(S), binds)
-
-    def cond(state):
-        _, _, rnd, active = state
-        return jnp.any(active) & (rnd < max_rounds)
-
-    def body(state):
-        assign, _, rnd, active = state
-
-        def slot(carry, s):
-            assign, total, changed = carry
-            k = jnp.take(mov_idx, s, axis=1)            # (B,) job id
-            ok = jnp.take(mov_ok, s, axis=1) & active
-            # width-1 toggle: fresh incumbent stats + job k's 3 moves,
-            # exact against the assignment as of THIS slot
-            tot, vals = _round_batched(assign, k[:, None], ok[:, None],
-                                       tc, dev, oi)
-            flat = vals[:, 0, :]                        # (B, 3)
-            m1 = jnp.argmin(flat, axis=1)
-            v1 = jnp.take_along_axis(flat, m1[:, None], axis=1)[:, 0]
-            improved = v1 < tot         # +inf masks no-ops and ~ok slots
-            assign = assign.at[binds, k].set(
-                jnp.where(improved, m1.astype(assign.dtype),
-                          assign[binds, k]))
-            # the carried value is the FRESH per-tier evaluation of the
-            # incumbent whenever the slot rejects its moves — so a
-            # converged ward (a full pass of rejections) always reports
-            # its final assignment's exact score; only a max_rounds cap
-            # can surface a (one-composition) delta-assembled value
-            total = jnp.where(improved, v1, tot)
-            return (assign, total, changed | improved), None
-
-        (assign, total, changed), _ = jax.lax.scan(
-            slot, (assign, jnp.full((B,), jnp.inf), jnp.zeros((B,), bool)),
-            jnp.arange(S))
-        return assign, total, rnd + 1, changed
-
-    state = (assign0, jnp.full((B,), jnp.inf), jnp.int32(0),
-             jnp.ones((B,), bool))
-    assign, totals, rounds, _ = jax.lax.while_loop(cond, body, state)
-    # max_rounds == 0 (greedy probe): the loop never evaluated anything
-    totals = jax.lax.cond(
-        rounds == 0,
-        lambda args: _round_batched(args[0], mov_idx, mov_ok, tc, dev,
-                                    oi)[0],
-        lambda args: args[1], (assign, totals))
-    return assign, totals, rounds
+        assign, totals, rounds = _run_rounds(
+            assign0, mov_idx, mov_ok, tc, dev, oi,
+            max_rounds * jnp.int32(S), binds)
+        return assign, totals, rounds, rounds + (rounds == 0)
+    return _run_passes(assign0, mov_idx, mov_ok, tc, dev, oi, max_rounds,
+                       _pass_replay if B == 1 else _pass_walk)
 
 
 def _reservation_rows(resv):
@@ -805,16 +893,16 @@ def _unpack(buf, layout):
 def _tabu_run_packed(buf, layout, objective: str,
                      greedy_init: bool = False, mode: str = "pass"):
     """`_tabu_run_batched` across one buffer each way: the inputs from the
-    packed buffer (`_unpack`), the result as one (B, n_max + 1) int32
-    array, the assignment in the first n_max columns and the float32
-    objective bit-cast into the last. The round count stays on the
-    device."""
-    assign, totals, _ = _tabu_run_batched(
+    packed buffer (`_unpack`), the result as one (B, n_max + 2) int32
+    array, the assignment in the first n_max columns, the float32
+    objective bit-cast into the next and the batch's tier-evaluation
+    count in the last. The round count stays on the device."""
+    assign, totals, _, evals = _tabu_run_batched(
         *_unpack(buf, layout), objective, greedy_init=greedy_init,
         mode=mode)
     return jnp.concatenate(
-        [assign, jax.lax.bitcast_convert_type(totals, jnp.int32)[:, None]],
-        axis=1)
+        [assign, jax.lax.bitcast_convert_type(totals, jnp.int32)[:, None],
+         jnp.broadcast_to(evals, (assign.shape[0], 1))], axis=1)
 
 
 def _per_instance_mpt(machines_per_tier, B: int):
@@ -961,9 +1049,10 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
         # static regime dispatch (DESIGN.md §12): movable-dominated batches
         # (movable bucket at least half the padded rows) take the wide
         # steepest-descent rounds; background-heavy batches take the
-        # width-1 movable-slot passes. Both sides of the threshold are a
-        # pure function of the batch's padded shape, so every ward of one
-        # call follows one regime and B = 1 replays it exactly.
+        # accept-as-you-go movable-slot passes. Both sides of the
+        # threshold are a pure function of the batch's padded shape, so
+        # every ward of one call follows one regime and B = 1 replays it
+        # exactly.
         mode = kernel_regime(layout[2], n_max)
     # what the dispatch moves and runs, all known on the host; counted
     # only while a recorder is armed
@@ -976,8 +1065,10 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
         out = _tabu_run_packed(buf, layout, objective,
                                greedy_init=initial is None, mode=mode)
     with spans.span("scheduler.fetch", **({"d2h_arrays": 1} if armed
-                                          else {})):
+                                          else {})) as fetch:
         out = np.asarray(out)
+        if armed and mode == "pass":
+            fetch.set(pass_evals=int(out[0, n_max + 1]))
     totals = out[:, n_max].view(np.float32).astype(np.float64)
     return totals, [out[b, :sizes[b]] for b in range(B)]
 

@@ -3,7 +3,7 @@ every replan carries the other wards' queued cloud work as reservation
 rows, counted apart from its own jobs on `scheduler.dispatch`
 (`reserved_rows`), with the request build's `wards` and `background`
 counts on `engine.requests`. Once reservations dominate a search it runs
-in the width-1 `pass` regime, and every answer is still a 1-move local
+in the `pass` regime, and every answer is still a 1-move local
 optimum of its subproblem under `simulator.simulate` with the same
 reservations, and every committed schedule is feasible."""
 from dataclasses import replace

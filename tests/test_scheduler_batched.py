@@ -269,7 +269,7 @@ class TestPackedBoundary:
         batch, kw = _packed_case(size, regime)
         (vals, assigns), seen = _captured_search(monkeypatch, batch, kw)
         host = _host_inputs(seen)
-        assign, totals, _ = scheduler_jax._tabu_run_batched(
+        assign, totals, _, _ = scheduler_jax._tabu_run_batched(
             *(host[name] for name, _ in scheduler_jax._PACKED_FIELDS),
             seen["objective"], greedy_init=seen["greedy_init"],
             mode=seen["mode"])
@@ -279,6 +279,245 @@ class TestPackedBoundary:
             assert assigns[b].dtype == np.int32
             assert np.array_equal(assigns[b],
                                   np.asarray(assign)[b, :len(jobs)])
+
+
+def _width1_reference(assign0, mov_idx, mov_ok, tc, dev, oi, max_rounds):
+    """The plain reference of the `pass` regime: the accept-as-you-go
+    walk of S width-1 slot evaluations per pass, as the kernel ran it
+    before the first-improvement replay. Returns (assign, totals,
+    rounds)."""
+    import jax
+    import jax.numpy as jnp
+    B = assign0.shape[0]
+    S = mov_idx.shape[1]
+    binds = jnp.arange(B)
+
+    def cond(state):
+        _, _, rnd, active = state
+        return jnp.any(active) & (rnd < max_rounds)
+
+    def body(state):
+        assign, _, rnd, active = state
+
+        def slot(carry, s):
+            assign, total, changed = carry
+            k = jnp.take(mov_idx, s, axis=1)
+            ok = jnp.take(mov_ok, s, axis=1) & active
+            tot, vals = scheduler_jax._round_batched(
+                assign, k[:, None], ok[:, None], tc, dev, oi)
+            flat = vals[:, 0, :]
+            m1 = jnp.argmin(flat, axis=1)
+            v1 = jnp.take_along_axis(flat, m1[:, None], axis=1)[:, 0]
+            improved = v1 < tot
+            assign = assign.at[binds, k].set(
+                jnp.where(improved, m1.astype(assign.dtype),
+                          assign[binds, k]))
+            total = jnp.where(improved, v1, tot)
+            return (assign, total, changed | improved), None
+
+        (assign, total, changed), _ = jax.lax.scan(
+            slot, (assign, jnp.full((B,), jnp.inf), jnp.zeros((B,), bool)),
+            jnp.arange(S))
+        return assign, total, rnd + 1, changed
+
+    state = (assign0, jnp.full((B,), jnp.inf), jnp.int32(0),
+             jnp.ones((B,), bool))
+    assign, totals, rounds, _ = jax.lax.while_loop(cond, body, state)
+    totals = jax.lax.cond(
+        rounds == 0,
+        lambda args: scheduler_jax._round_batched(
+            args[0], mov_idx, mov_ok, tc, dev, oi)[0],
+        lambda args: args[1], (assign, totals))
+    return assign, totals, rounds
+
+
+def _pass_runs(host, objective):
+    """-> {name: (assign, totals, rounds)} of the packed inputs `host`
+    under the reference walk, the replay at any B (`_pass_replay` through
+    `_run_passes`) and the kernel as dispatched."""
+    import jax
+    oi = scheduler_jax._OBJ_IDX[objective]
+    args = [host[name] for name, _ in scheduler_jax._PACKED_FIELDS]
+
+    @jax.jit
+    def both(assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
+             max_rounds, busy_c, busy_e):
+        tc, dev = scheduler_jax._kernel_consts(rel, w, proc, trans,
+                                               busy_c, busy_e)
+        ref = _width1_reference(assign0, mov_idx, mov_ok, tc, dev, oi,
+                                max_rounds)
+        rep = scheduler_jax._run_passes(assign0, mov_idx, mov_ok, tc, dev,
+                                        oi, max_rounds,
+                                        scheduler_jax._pass_replay)
+        return ref, rep[:3], rep[3]
+
+    ref, rep, evals = both(*args)
+    kernel = scheduler_jax._tabu_run_batched(*args, objective, mode="pass")
+    out = {"reference": ref, "replay": rep, "kernel": kernel[:3]}
+    return {k: tuple(np.asarray(x) for x in v) for k, v in out.items()}, \
+        int(evals)
+
+
+def _metro_ward(rng, n_jobs, objective, fleet, n_resv=27, converged=False,
+                last_bad=False):
+    """One ward in the metro replan's B = 1 shape: `n_jobs` movable jobs
+    from a random initial and `n_resv` reservation rows of other wards'
+    cloud work (a few on the edge), padded to 48 rows and 16 slots.
+    `last_bad` starts the last movable job on a device it is far too slow
+    on, so its slot accepts; `converged` starts from the ward's own
+    searched plan, so its first pass accepts nothing."""
+    jobs = _random_jobs(rng, n_jobs)
+    if last_bad:
+        j = jobs[-1]
+        jobs[-1] = JobSpec(name=j.name, release=j.release, weight=j.weight,
+                           proc={**j.proc, ED: 500.0}, trans=j.trans)
+    resv = {CC: [Reservation(arrival=float(rng.integers(0, 60)),
+                             proc=float(rng.integers(1, 20)),
+                             release=float(rng.integers(0, 30)),
+                             weight=float(rng.integers(1, 3)))
+                 for _ in range(n_resv - n_resv // 9)],
+            ES: [Reservation(arrival=float(rng.integers(0, 40)),
+                             proc=float(rng.integers(1, 10)),
+                             release=float(rng.integers(0, 30)))
+                 for _ in range(n_resv // 9)]}
+    initial = [int(x) for x in rng.integers(0, 3, n_jobs)]
+    if last_bad:
+        initial[-1] = 2
+    busy = ([float(rng.integers(0, 20))], [])
+    if converged:
+        _, plan = scheduler_jax.tabu_search_jax(
+            jobs, initial, objective=objective, machines_per_tier=fleet,
+            busy_until=busy, reserved=resv)
+        initial = [int(x) for x in plan]
+    return jobs, initial, resv, busy
+
+
+# (objective, fleet, movable jobs per ward, max_rounds, ward options)
+_PASS_CASES = {
+    "weighted-1x1": ("weighted", (1, 1), (6,), 50, [{}]),
+    "unweighted-15x1": ("unweighted", (15, 1), (8,), 50, [{}]),
+    "last-2x3": ("last", (2, 3), (5,), 50, [{}]),
+    "weighted-15x1": ("weighted", (15, 1), (10,), 50, [{}]),
+    "unweighted-2x3": ("unweighted", (2, 3), (3,), 50, [{}]),
+    "last-15x1": ("last", (15, 1), (7,), 50, [{}]),
+    "cap-hit": ("weighted", (15, 1), (10,), 1, [{}]),
+    "last-real-slot-accepts": ("weighted", (15, 1), (4,), 50,
+                               [{"last_bad": True}]),
+    "slot-S-1-accepts": ("unweighted", (2, 3), (16,), 50,
+                         [{"last_bad": True}]),
+    "B3-one-ward-inactive": ("weighted", (15, 1), (9, 4, 6), 50,
+                             [{}, {"converged": True}, {"last_bad": True}]),
+    "B3-last-2x3": ("last", (2, 3), (2, 10, 5), 50, [{}, {}, {}]),
+}
+
+
+@pytest.mark.parametrize("case", list(_PASS_CASES))
+def test_first_improvement_pass_equals_the_width1_walk(monkeypatch, case):
+    """The `pass` regime's replay (DESIGN.md §12) gives the reference
+    walk's assignments, objective bits and pass count on every ward."""
+    objective, fleet, sizes, max_rounds, opts = _PASS_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    wards = [_metro_ward(rng, n, objective, fleet, **o)
+             for n, o in zip(sizes, opts)]
+    batch = [w[0] for w in wards]
+    kw = dict(initial=[w[1] for w in wards], reserved=[w[2] for w in wards],
+              busy_until=[w[3] for w in wards],
+              machines_per_tier=[fleet] * len(wards),
+              max_rounds=max_rounds, objective=objective, pad_to=48)
+    _, seen = _captured_search(monkeypatch, batch, kw)
+    assert seen["mode"] == "pass" and seen["layout"][1:3] == (48, 16)
+    runs, evals = _pass_runs(_host_inputs(seen), objective)
+    ref_assign, ref_totals, ref_rounds = runs["reference"]
+    for name in ("replay", "kernel"):
+        assign, totals, rounds = runs[name]
+        assert np.array_equal(assign, ref_assign), name
+        assert np.array_equal(totals.view(np.int32),
+                              ref_totals.view(np.int32)), name
+        assert rounds == ref_rounds, name
+    assign0 = _host_inputs(seen)["assign0"]
+    moved = (ref_assign != assign0).any(axis=1)
+    assert moved.any()                       # the walk had work to do
+    assert evals <= ref_rounds * 16
+    if case == "cap-hit":
+        assert ref_rounds == 1
+        uncapped = scheduler_jax.tabu_search_batched(
+            batch, **dict(kw, max_rounds=50))[1][0]
+        assert not np.array_equal(uncapped, ref_assign[0, :len(batch[0])])
+    if case == "B3-one-ward-inactive":
+        assert not moved[1]
+    if "accepts" in case:
+        last = len(batch[0]) - 1
+        assert ref_assign[0, last] != 2 and assign0[0, last] == 2
+
+
+def test_a_batch_equals_each_ward_searched_alone():
+    """B > 1 takes the width-1 walk and B = 1 the replay: every ward of a
+    batch comes out bit for bit as its own B = 1 search."""
+    rng = np.random.default_rng(17)
+    for objective, fleet in (("weighted", (15, 1)), ("last", (2, 3))):
+        wards = [_metro_ward(rng, n, objective, fleet, **o) for n, o in
+                 zip((9, 4, 6), ({}, {"converged": True}, {}))]
+        vals, assigns = scheduler_jax.tabu_search_batched(
+            [w[0] for w in wards], [w[1] for w in wards],
+            objective=objective, machines_per_tier=fleet,
+            busy_until=[w[3] for w in wards],
+            reserved=[w[2] for w in wards], pad_to=48)
+        for (jobs, initial, resv, busy), v, a in zip(wards, vals, assigns):
+            v1, a1 = scheduler_jax.tabu_search_batched(
+                [jobs], [initial], objective=objective,
+                machines_per_tier=fleet, busy_until=[busy],
+                reserved=[resv], pad_to=48)
+            assert np.array_equal(a, a1[0])
+            assert np.float32(v).view(np.int32) == \
+                np.float32(v1[0]).view(np.int32)
+
+
+def _fetched(monkeypatch, armed):
+    """The attrs set on `scheduler.fetch` by one two-job search padded
+    into the `pass` regime: job A starts on a device it is far too slow
+    on and moves to the edge at slot 0; job B already sits on the edge,
+    and the cloud's transfer is too long for either. One move, two
+    passes (the second accepts nothing). Unarmed, the attrs the program
+    sets on the shared no-op span."""
+    from repro.utils import spans
+    jobs = [JobSpec(name=n, release=0.0, weight=1.0,
+                    proc={CC: 1.0, ES: 2.0, ED: 900.0},
+                    trans={CC: 500.0, ES: 1.0, ED: 0.0}) for n in "AB"]
+    if not armed:
+        seen = [{}]
+        monkeypatch.setattr(spans._Off, "set",
+                            lambda self, **attrs: seen[0].update(attrs))
+        _, assigns = scheduler_jax.tabu_search_batched(
+            [jobs], [[2, 1]], pad_to=48)
+        return assigns[0].tolist(), seen
+    with spans.recording() as rec:
+        _, assigns = scheduler_jax.tabu_search_batched(
+            [jobs], [[2, 1]], pad_to=48)
+    return assigns[0].tolist(), [s.attrs for s in rec.spans
+                                 if s.name == "scheduler.fetch"]
+
+
+def test_pass_evals_counts_moves_plus_passes(monkeypatch):
+    assign, (fetch,) = _fetched(monkeypatch, True)
+    assert assign == [1, 1]                  # the one move: A to the edge
+    assert fetch["pass_evals"] == 1 + 2 and fetch["d2h_arrays"] == 1
+
+
+@pytest.mark.parametrize("why", ["unarmed", "round"])
+def test_pass_evals_only_on_armed_pass_searches(monkeypatch, why):
+    if why == "unarmed":
+        assign, (fetch,) = _fetched(monkeypatch, False)
+    else:                                    # 16 rows: the round regime
+        from repro.utils import spans
+        jobs = _random_jobs(np.random.default_rng(3), 6)
+        with spans.recording() as rec:
+            scheduler_jax.tabu_search_batched([jobs])
+        (fetch,) = [s.attrs for s in rec.spans
+                    if s.name == "scheduler.fetch"]
+        (dispatch,) = [s.attrs for s in rec.spans
+                       if s.name == "scheduler.dispatch"]
+        assert dispatch["regime"] == "round"
+    assert "pass_evals" not in fetch
 
 
 class TestSearchBatchedDispatch:

@@ -103,7 +103,7 @@ def test_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows, slots):
 ])
 def test_packed_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows,
                                              slots):
-    # the served entry: one int32 buffer in, one (B, rows + 1) array out
+    # the served entry: one int32 buffer in, one (B, rows + 2) array out
     layout = (batch, rows, slots, 1, 1)
     _, size = scheduler_jax._packed_fields(layout)
     buf = _spec((size,), jnp.int32, one_chip)
@@ -112,7 +112,7 @@ def test_packed_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows,
     compiled = lowered.compile()
     assert compiled.memory_analysis() is not None
     (out,) = jax.tree.leaves(lowered.out_info)
-    assert out.shape == (batch, rows + 1) and out.dtype == jnp.int32
+    assert out.shape == (batch, rows + 2) and out.dtype == jnp.int32
     assert scheduler_jax.kernel_regime(slots, rows) == mode
     assert "while" in compiled.as_text()
 
