@@ -34,9 +34,10 @@ hardware — regenerate the baseline when the CI host changes):
     hard invariants whenever the fresh section exists:
     ``parity_with_phantom`` must be True (the interval background must
     reproduce the frozen-phantom plan bit-identically or strictly beat
-    it fleet-true) and the compiled-shape cache must report zero
-    evictions (the §12 bucketing contract keeps the benchmark inside a
-    handful of compiled shapes);
+    it fleet-true) and ``compiled_programs``, the programs the device
+    search's jitted entry compiled after the section's warm-up, must not
+    exceed the committed count of 0 (the §12 bucketing contract makes
+    the timed run all hits);
   * metro ``events_per_s`` and ``miss_rate_improvement`` — the streaming
     traffic engine must keep its event throughput and the tabu-vs-greedy
     deadline miss-rate win (DESIGN.md §10); plus the hard invariant that
@@ -228,18 +229,21 @@ def compare(committed: dict, fresh: dict, tolerance: float = 0.30,
     if ci:
         # hard invariants (DESIGN.md §12): the interval background must
         # reproduce the frozen-phantom oracle's plan (or strictly beat
-        # it fleet-true), and the bucketed dispatch cache must absorb
-        # the benchmark's shape traffic without a single eviction
+        # it fleet-true), and after its warm-up the section must compile
+        # no more device-search programs than the committed run did
         if not ci.get("parity_with_phantom", False):
             problems.append(
                 "contention_interval/parity_with_phantom: False "
                 "(interval background diverged from the frozen-phantom "
                 "construction without beating it fleet-true)")
-        evs = (ci.get("compiled_shapes") or {}).get("evictions", 0)
-        if evs:
+        com_ci = committed.get("contention_interval") or {}
+        progs = ci.get("compiled_programs")
+        limit = com_ci.get("compiled_programs")
+        if progs is not None and limit is not None and progs > limit:
             problems.append(
-                f"contention_interval/compiled_shapes.evictions: {evs} "
-                f"!= 0 (§12 bucketing no longer bounds shape churn)")
+                f"contention_interval/compiled_programs: {progs} > "
+                f"committed {limit} (§12 bucketing no longer bounds "
+                f"shape churn)")
     metro = fresh.get("metro") or {}
     if metro:
         # hard invariant (DESIGN.md §10): committed tabu replanning must

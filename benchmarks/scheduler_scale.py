@@ -7,8 +7,8 @@ Head-to-head Algorithm-2 implementations (the repo's single hottest path):
   * incremental — the ScheduleState-backed tabu search
     (scheduler.neighborhood_search), O(two queues) per candidate move;
   * jax — the fully jitted neighbourhood search
-    (scheduler_jax.tabu_search_jax), one vmapped n x 3 neighbourhood
-    evaluation per lax.while_loop round, no host syncs.
+    (scheduler_jax.tabu_search_batched on one instance), one n x 3
+    neighbourhood evaluation per lax.while_loop round, no host syncs.
 
 Also: JAX batched-evaluation throughput, heuristic optimality gap,
 fleet-scale batched planning throughput in wards/sec (``batched`` section:
@@ -90,9 +90,9 @@ def bench_head_to_head(sizes=(10, 100, 1000), max_count=5):
 
         # compile outside the timed region: the jitted search is reused
         # across replans of the same instance size in serving
-        scheduler_jax.tabu_search_jax(jobs, max_rounds=1)
-        dt, (_, a) = _time(lambda: scheduler_jax.tabu_search_jax(
-            jobs, max_rounds=max_count))
+        scheduler_jax.tabu_search_batched([jobs], max_rounds=1)
+        dt, (_, (a,)) = _time(lambda: scheduler_jax.tabu_search_batched(
+            [jobs], max_rounds=max_count))
         # score the returned assignment with the exact (float64) simulator
         # so all three methods' objectives share one evaluator
         exact = simulate(jobs, [MACHINES[int(i)] for i in a])
@@ -137,7 +137,7 @@ def bench_batched(wards=32, n=100, max_count=5, repeats=3):
     call (tabu_search_batched) vs the sequential per-instance loop the
     repo used before the batched subsystem existed (`scheduler.search`
     per ward — on CPU that's the incremental Python path; also timed: a
-    per-instance jitted `tabu_search_jax` loop). Both sides are measured
+    loop of per-instance (B = 1) jitted searches). Both sides are measured
     best-of-`repeats` after a warm-up call so jit compiles and load
     spikes don't skew the ratio. Batched-vs-per-instance disagreements
     after exact re-simulation are recorded as ``parity_mismatches``
@@ -158,12 +158,12 @@ def bench_batched(wards=32, n=100, max_count=5, repeats=3):
         return best, out
 
     scheduler_jax.tabu_search_batched(instances, max_rounds=1)   # compile
-    scheduler_jax.tabu_search_jax(instances[0], max_rounds=1)
+    scheduler_jax.tabu_search_batched(instances[:1], max_rounds=1)
     t_batched, (_, assigns_b) = _best_of(
         lambda: scheduler_jax.tabu_search_batched(
             instances, max_rounds=max_rounds))
     t_jax_loop, assigns_s = _best_of(lambda: [
-        scheduler_jax.tabu_search_jax(jobs, max_rounds=max_rounds)[1]
+        scheduler_jax.tabu_search_batched([jobs], max_rounds=max_rounds)[1][0]
         for jobs in instances])
     t_search_loop, _ = _best_of(lambda: [
         scheduler.search(jobs, max_count=max_count) for jobs in instances])
@@ -251,9 +251,10 @@ def bench_contention_interval(wards=32, n=100, cloud_machines=4,
     ``parity_with_phantom`` is a hard invariant downstream: the interval
     plan must reproduce the frozen-phantom construction's plan
     bit-identically, or strictly beat its fleet-true objective.
-    ``compiled_shapes`` surfaces the bucketed-dispatch cache counters
-    (§3.3): under a healthy bucketing contract the timed run is all
-    hits, no evictions."""
+    ``compiled_programs`` counts the programs the device search's jitted
+    entry compiled after the warm-up, over the timed and parity runs: the
+    §12 bucketing contract makes those all hits, and `check_regression.py`
+    fails when the count grows past the committed one."""
     from repro.core.problems import metro_jobs
 
     instances = [metro_jobs(np.random.default_rng(5000 + i), n=n)
@@ -265,6 +266,7 @@ def bench_contention_interval(wards=32, n=100, cloud_machines=4,
     # same first-sweep background, so the warmed bucket is the timed one
     scheduler.search_fleet(instances, machines_per_tier=mpt,
                            max_count=max_count, max_sweeps=1)
+    programs0 = scheduler_jax._tabu_run_packed._cache_size()
     t0 = time.perf_counter()
     plan = scheduler.search_fleet(instances, machines_per_tier=mpt,
                                   max_count=max_count,
@@ -300,7 +302,8 @@ def bench_contention_interval(wards=32, n=100, cloud_machines=4,
         "fraction_of_batched": t_indep / seconds,
         "phantom_fleet_true": phantom.fleet.weighted_sum,
         "parity_with_phantom": bool(parity),
-        "compiled_shapes": scheduler.compiled_shape_stats(),
+        "compiled_programs":
+        scheduler_jax._tabu_run_packed._cache_size() - programs0,
     }
 
 
@@ -336,9 +339,6 @@ def bench_metro(wards=4, hours=2.0, seed=0):
         "p50": {k: v["p50"] for k, v in out.items()},
         "p99": {k: v["p99"] for k, v in out.items()},
         "utilization_tabu": t["utilization"],
-        # §3.3 bucketed-dispatch cache counters after the three runs —
-        # `serve --metro` prints the same line (PR 10, DESIGN.md §15)
-        "compiled_shapes": scheduler.compiled_shape_stats(),
     }
 
 
@@ -365,8 +365,8 @@ def bench_metro_scenarios(packs=CHAOS_PACKS, seed=0):
     life-critical miss-rate improvement vs greedy (the admission-control
     claim: sacrificing a bounded share of the lowest-weight class must
     protect the life-critical SLA). The search backend is pinned to the
-    Python path so the committed numbers are call-order-independent
-    (metro.engine's determinism note)."""
+    Python path, so the committed numbers come from one backend on any
+    platform."""
     from repro.launch.serve import run_metro
 
     out = {}
@@ -410,8 +410,8 @@ def bench_metro_hedging(seed=0):
     tolerance — the hedged run must strictly beat the unhedged run on
     both the life-critical miss rate (`critical_improvement_hedge`) and
     the p99 response (`p99_improvement_hedge`). The search backend is
-    pinned to the Python path so the committed numbers are
-    call-order-independent (metro.engine's determinism note)."""
+    pinned to the Python path, so the committed numbers come from one
+    backend on any platform."""
     from repro.launch.serve import run_metro
 
     def one(hedged):
@@ -455,8 +455,8 @@ def bench_metro_observability(seed=0):
     the aggregate ``events_per_s_retention`` (traced throughput as a
     fraction of untraced over all packs), which the gate holds above
     1/1.15: the armed recorder may cost at most 15%. The search backend
-    is pinned to the Python path so both runs replay identical
-    decisions (metro.engine's determinism note)."""
+    is pinned to the Python path, so the numbers come from one backend
+    on any platform."""
     import zlib
 
     from repro.metro import (HedgingPolicy, make_policy, simulate_metro,
@@ -659,7 +659,6 @@ def bench_scheduler_scale(with_online_scenarios: bool = False,
     ci = report["contention_interval"]
     rows.append(("contention_interval_wards", ci["wards"], ci["seconds"],
                  ci["wards_per_s"]))
-    shapes = ci["compiled_shapes"]
     csv.append(
         f"sched_contention_interval_B{ci['wards']}_n{ci['n']},"
         f"{ci['seconds']*1e6:.0f},"
@@ -668,8 +667,7 @@ def bench_scheduler_scale(with_online_scenarios: bool = False,
         f"wards_per_s={ci['wards_per_s']:.1f};"
         f"fraction_of_batched={ci['fraction_of_batched']:.2f};"
         f"parity_with_phantom={ci['parity_with_phantom']};"
-        f"shape_cache_hits={shapes['hits']};"
-        f"shape_cache_evictions={shapes['evictions']}")
+        f"compiled_programs={ci['compiled_programs']}")
 
     # 5c) streaming metro traffic: policy comparison + engine throughput
     # (DESIGN.md §10)

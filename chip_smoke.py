@@ -48,6 +48,7 @@ from repro.core.tiers import CC, ES, paper_tiers  # noqa: E402
 from repro.data import icu  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.launch import serve  # noqa: E402
+from repro.utils import spans  # noqa: E402
 from repro.utils.compile_cache import enable_compilation_cache  # noqa: E402
 
 OURS = "ours (algorithm 2)"
@@ -65,9 +66,9 @@ LOGITS_ATOL = 1e-2
 OBJECTIVE_RTOL = 1e-5
 
 
-def _dispatches() -> int:
-    stats = scheduler.compiled_shape_stats()
-    return stats["hits"] + stats["misses"]
+def _device_searches(rec: spans.Recorder) -> int:
+    """Device searches in a recording: one `scheduler.dispatch` each."""
+    return len(rec.durations("scheduler.dispatch"))
 
 
 def _check(ok: bool, what: str) -> None:
@@ -101,15 +102,15 @@ def reference_logits(params, x, cfg):
 def phase_icu(patients: int = 200) -> dict:
     """serve.run on the TPU tiers with execution, then each ICU
     forward's kernel and numerics."""
-    before = _dispatches()
-    results, lb = serve.run(patients=patients, tiers_kind="tpu",
-                            execute=True, verbose=False)
+    with spans.recording() as rec:
+        results, lb = serve.run(patients=patients, tiers_kind="tpu",
+                                execute=True, verbose=False)
     ours = results[OURS].weighted_sum
     for name, sched in results.items():
         _check(ours <= sched.weighted_sum,
                f"ours {ours} > {name} {sched.weighted_sum}")
     _check(ours >= lb, f"ours {ours} < lower bound {lb}")
-    on_device = _dispatches() > before
+    on_device = _device_searches(rec) > 0
     want_device = (patients > scheduler.JAX_SEARCH_THRESHOLD
                    and jax.default_backend() != "cpu")
     _check(on_device == want_device,
@@ -251,21 +252,20 @@ def phase_metro(wards=None, hours=None) -> dict:
     """serve.run_metro on `mass_casualty_crash` (its canonical wards and
     hours by default) with every replan on the device search, the
     sanitizer armed and each policy replayed twice."""
-    before = _dispatches()
-    out = serve.run_metro(wards=wards, hours=hours,
-                          scenario="mass_casualty_crash",
-                          policies=("greedy", "tabu", "fleet"),
-                          sanitize=True, check_determinism=True,
-                          jax_threshold=0, verbose=False)
-    stats = scheduler.compiled_shape_stats()
-    _check(stats["size"] > 0, f"compiled shapes {stats}")
-    _check(_dispatches() > before, "no replan reached the device search")
+    with spans.recording() as rec:
+        out = serve.run_metro(wards=wards, hours=hours,
+                              scenario="mass_casualty_crash",
+                              policies=("greedy", "tabu", "fleet"),
+                              sanitize=True, check_determinism=True,
+                              jax_threshold=0, verbose=False)
+    device_searches = _device_searches(rec)
+    _check(device_searches > 0, "no replan reached the device search")
     policies = {name: {"event_log_crc": f"{s['event_log_hash']:#010x}",
                        "completions": s["completions"],
                        "miss_rate": s["miss_rate"],
                        "critical_miss_rate": s["critical_miss_rate"]}
                 for name, s in out.items()}
-    return {"compiled_shapes": stats, "policies": policies}
+    return {"device_searches": device_searches, "policies": policies}
 
 
 def main() -> int:

@@ -370,7 +370,7 @@ class TracedPythonLeakRule(Rule):
 
 # ------------------------------------------------------------------ R006
 
-_CACHED_DISPATCH = ("tabu_search_jax", "tabu_search_batched")
+_CACHED_DISPATCH = ("tabu_search_batched",)
 _DISPATCH_HOME = ("core/scheduler.py", "core/scheduler_jax.py")
 _AOT_ATTRS = {"lower", "trace", "eval_shape"}
 
@@ -378,15 +378,14 @@ _AOT_ATTRS = {"lower", "trace", "eval_shape"}
 class JitDispatchBypassRule(Rule):
     """`jax.jit(f)(x)` builds a FRESH jit wrapper per call — every
     invocation retraces and recompiles. Hoist the jitted callable to a
-    module/instance attribute. Likewise, calling the raw jitted
-    scheduler kernels (`tabu_search_jax`/`tabu_search_batched`)
-    anywhere but `scheduler.search`'s dispatcher bypasses the
-    `_COMPILED_SHAPES` bucketed compile cache (DESIGN.md §3.3/§12) —
-    shapes stop being bucketed and the retrace cost comes back.
+    module/instance attribute. Likewise, calling the device search
+    (`tabu_search_batched`) anywhere but `core/scheduler.py` skips
+    `scheduler.search`'s `pad_to` row bucketing (DESIGN.md §12): the
+    jitted kernel then retraces at every new instance size.
     AOT use (`jax.jit(f).lower(...)`) is exempt: lowering is an
     explicit one-shot compile."""
     id = "R006"
-    title = "jit dispatch bypassing the bucketed compile cache"
+    title = "jit dispatch that retraces per call or per size"
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:
         aliases = import_aliases(ctx.tree)
@@ -416,9 +415,9 @@ class JitDispatchBypassRule(Rule):
                     name.split(".")[-1] in _CACHED_DISPATCH:
                 yield ctx.finding(
                     self.id, node,
-                    f"direct `{name.split('.')[-1]}` call bypasses "
-                    f"scheduler.search's _COMPILED_SHAPES bucketed "
-                    f"dispatch (retrace hazard); route through "
+                    f"direct `{name.split('.')[-1]}` call skips "
+                    f"scheduler.search's pad_to row bucketing and "
+                    f"retraces per size; route through "
                     f"scheduler.search/search_batched")
 
 

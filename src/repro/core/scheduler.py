@@ -14,9 +14,12 @@ Pipeline:
      queues); the returned Schedule is always a final exact re-simulation,
      so reported numbers always reflect C1-C5 semantics.
 
-`search` dispatches between this Python path (small n) and the fully
-jitted JAX neighbourhood search (scheduler_jax.tabu_search_jax) above
-JAX_SEARCH_THRESHOLD jobs — see DESIGN.md §3.3 for the policy.
+`search` dispatches between this Python path and the fully jitted JAX
+neighbourhood search (scheduler_jax.tabu_search_batched) by a pure
+function of the call's own arguments and the platform (DESIGN.md §3.3):
+with an explicit ``jax_threshold`` the device search runs when
+n > jax_threshold; otherwise it runs when n > JAX_SEARCH_THRESHOLD on an
+accelerator, and never on the CPU. No earlier call changes the choice.
 
 Also provides baseline strategies (Table VII comparison set), an exact
 brute-force optimum for small n (the paper has none — we add it to measure
@@ -45,78 +48,10 @@ JAX_SEARCH_THRESHOLD = 64
 # search (DESIGN.md §8); smaller ones loop the per-instance `search`
 BATCHED_SEARCH_MIN_WARDS = 4
 
-# BUCKETED (rows, movable, fleet, objective) shapes the jitted solo
-# search has already compiled IN THIS PROCESS. On CPU the delta-evaluated
-# kernel beats the incremental Python path once compiled (DESIGN.md
-# §3.3), but a fresh XLA trace costs seconds — so `search` only
-# dispatches a CPU call to JAX when its shape is in here, i.e. when some
-# earlier call (benchmark warm-up, explicit jax_threshold, TPU run)
-# already paid the compile. Replanning loops with repeating shapes (the
-# metro engine) then ride the compiled kernel for free.
-#
-# The key buckets both the padded row count (jobs + reservations) and
-# the movable count up to multiples of 16 (DESIGN.md §12) — the same
-# padding the kernel itself applies — so metro load, where the movable
-# count drifts at every event, maps to a handful of compiled shapes
-# instead of one per event. The cache is CAPPED: a miss at the cap
-# clears it AND the underlying jit caches, so a pathological shape
-# churn degrades to retracing instead of unbounded compiled-program
-# growth. `compiled_shape_stats()` surfaces hit/miss/eviction counters
-# (recorded by benchmarks/scheduler_scale.py) so retrace regressions
-# under metro load are visible, not just slow.
-#
-# Note the trade this makes explicit: the two backends are both exact
-# C1-C5 searches but follow different trajectories (paired moves, §8),
-# so they can return DIFFERENT valid local optima — a cache hit changes
-# which one a later same-shape call gets. `search` results are therefore
-# deterministic per (inputs, dispatch state), not per inputs alone;
-# callers that need call-order-independent output pin the backend with
-# an explicit jax_threshold. The committed benchmarks run each section
-# in a fixed order in a fresh process, so their numbers are stable.
-_COMPILED_SHAPES: set = set()
-_COMPILED_SHAPES_CAP = 64
-_SHAPE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
-
-
 def _bucket16(x: int) -> int:
     """§12 bucketing contract: kernel sizes round up to multiples of 16
     (minimum 16), matching scheduler_jax's movable-slot padding."""
     return ((max(int(x), 1) + 15) // 16) * 16
-
-
-def _batched_shape(B, rows, n_mov, pairs, objective):
-    """Bucketed cache key for one BATCHED kernel dispatch. Tagged with a
-    leading "batched" so it can never satisfy the solo fast-path lookup
-    (a batched compile at B=32 does not warm the B=1 solo kernel)."""
-    return ("batched", B, rows, min(rows, _bucket16(n_mov)),
-            (max(c for c, _ in pairs), max(e for _, e in pairs)), objective)
-
-
-def compiled_shape_stats() -> Dict[str, int]:
-    """Dispatch cache counters: {size, hits, misses, evictions}, covering
-    solo fast-path dispatches and batched kernel calls alike.
-
-    A healthy metro run shows misses plateauing after warm-up while hits
-    keep climbing; rising misses (or any eviction) under steady load
-    means the bucketing no longer covers the traffic's shape churn."""
-    return {"size": len(_COMPILED_SHAPES), **_SHAPE_STATS}
-
-
-def _note_shape(shape) -> None:
-    """Record one JAX dispatch of `shape` — a solo fast-path key or a
-    `_batched_shape` key (hit or miss); on a miss
-    at the cap, drop every compiled shape — ours and jit's — rather than
-    let compiled programs accumulate without bound."""
-    if shape in _COMPILED_SHAPES:
-        _SHAPE_STATS["hits"] += 1
-        return
-    _SHAPE_STATS["misses"] += 1
-    if len(_COMPILED_SHAPES) >= _COMPILED_SHAPES_CAP:
-        _COMPILED_SHAPES.clear()
-        _SHAPE_STATS["evictions"] += 1
-        import jax
-        jax.clear_caches()
-    _COMPILED_SHAPES.add(shape)
 
 
 # --------------------------------------------------------------- strategies
@@ -303,45 +238,29 @@ def search(jobs: Sequence[JobSpec],
     vmapped n x 3 neighbourhood evaluation per round inside lax.while_loop,
     no host syncs) for large ones. Both return an exact C1-C5 Schedule.
 
-    jax_threshold: job count above which the JAX path is taken. Default
-    (None): JAX_SEARCH_THRESHOLD when an accelerator backend is present,
-    never on CPU. Since the delta-evaluation rewrite the jitted search
-    wins on CPU too once compiled (n=100 and n=1000 both, DESIGN.md
-    §3.3), but each new (instance size, fleet) shape pays a multi-second
-    XLA compile — replanning loops see a different size at every event,
-    so the Python path stays the CPU default. Pass an explicit threshold
-    to force the JAX path where shapes repeat (benchmarks, serving, TPU
-    deployments); fleet planning over many wards should use
-    `search_batched`, which amortises one compile across the batch.
+    Backend rule (DESIGN.md §3.3), a function of the call's own
+    arguments and the platform alone: with an explicit jax_threshold the
+    JAX path runs when n > jax_threshold; with None it runs when
+    n > JAX_SEARCH_THRESHOLD on an accelerator backend, and never on the
+    CPU. The two backends are both exact C1-C5 searches but may return
+    different local optima (paired moves, §8), so pinning the threshold
+    pins the result. The JAX call pads its instance to the bucketed row
+    count (§12), so calls whose sizes land in one bucket share one
+    compiled kernel: under metro load the movable count drifts at every
+    event, and without the bucketing each drift would be a fresh trace.
+    Fleet planning over many wards should use `search_batched`, which
+    amortises one dispatch across the batch.
 
     machines_per_tier / busy_until (DESIGN.md §7), frozen (DESIGN.md §9:
     immovable background jobs, initial required) and reserved
     (DESIGN.md §12: committed interval occupancy, initial required) are
     threaded through whichever backend runs, so both search the problem
     the schedule will actually be committed against.
-
-    Compiled-shape fast path: a CPU call whose BUCKETED (rows, movable,
-    fleet, objective) shape some earlier call already compiled
-    (`_COMPILED_SHAPES`) dispatches to JAX even below the threshold —
-    the compile is sunk, and once compiled the jitted search wins on CPU
-    too (DESIGN.md §3.3). The JAX call pads its instance to the bucketed
-    row count (§12), so every call whose sizes land in one bucket hits
-    ONE compiled kernel — under metro load the movable count drifts at
-    every event, and without the bucketing each drift would be a fresh
-    multi-second trace.
     """
     with spans.span("scheduler.search"):
         n = len(jobs)
-        mpt = dict(machines_per_tier or {})
-        mpt_jax = (int(mpt.get(CC, 1)), int(mpt.get(ES, 1)))
-        n_res = sum(len(v) for v in (reserved or {}).values())
-        n_mov = n - (sum(map(bool, frozen)) if frozen is not None else 0)
-        rows = _bucket16(n + n_res)
-        shape = (rows, min(rows, _bucket16(n_mov)), mpt_jax, objective)
         if jax_threshold is None:
-            use_jax = (n > JAX_SEARCH_THRESHOLD
-                       and _accelerator_backend()) \
-                or shape in _COMPILED_SHAPES
+            use_jax = n > JAX_SEARCH_THRESHOLD and _accelerator_backend()
         else:
             use_jax = n > jax_threshold
         if not use_jax:
@@ -350,6 +269,9 @@ def search(jobs: Sequence[JobSpec],
                 objective=objective, machines_per_tier=machines_per_tier,
                 busy_until=busy_until, frozen=frozen, reserved=reserved)
         from repro.core import scheduler_jax   # lazy: jax off small paths
+        mpt = dict(machines_per_tier or {})
+        mpt_jax = (int(mpt.get(CC, 1)), int(mpt.get(ES, 1)))
+        n_res = sum(len(v) for v in (reserved or {}).values())
         if frozen is not None and any(frozen) and initial is None:
             raise ValueError("frozen jobs require an explicit initial "
                              "assignment carrying their pinned tiers")
@@ -368,8 +290,7 @@ def search(jobs: Sequence[JobSpec],
             machines_per_tier=[mpt_jax], busy_until=[busy_jax],
             frozen=None if frozen is None else [list(frozen)],
             reserved=None if reserved is None else [reserved],
-            pad_to=rows)
-        _note_shape(shape)
+            pad_to=_bucket16(n + n_res))
         with spans.span("scheduler.rescore"):
             return simulate(jobs, [MACHINES[int(m)] for m in assigns[0]],
                             machines_per_tier=machines_per_tier,
@@ -395,13 +316,15 @@ def search_batched(problems: Sequence[Sequence[JobSpec]],
     path with phantom jobs that contribute exactly 0 to every
     objective). machines_per_tier: one {tier: count} mapping for every
     ward or a per-ward sequence of mappings; busy_until: optional
-    per-ward {tier: [free times]} sequence. min_batch: batches smaller
-    than this loop the per-instance `search` instead (default
-    BATCHED_SEARCH_MIN_WARDS — tiny fleets don't amortise a device
-    dispatch); pass 1 to force the batched path, a large value to force
-    the sequential loop. jax_threshold is forwarded to the sequential
-    fallback's per-instance `search` calls, so small batches dispatch to
-    the same backend their caller asked large ones to use (§3.3).
+    per-ward {tier: [free times]} sequence.
+
+    Backend rule (DESIGN.md §3.3), a function of the call's own
+    arguments and the platform alone: B >= min_batch (default
+    BATCHED_SEARCH_MIN_WARDS; tiny fleets don't amortise a device
+    dispatch) takes the one batched device call; a smaller batch loops
+    the per-instance `search`, each call with this jax_threshold and so
+    under `search`'s own rule. Pass min_batch=1 to force the batched
+    path, a large value to force the sequential loop.
 
     initial / frozen (DESIGN.md §9): optional per-ward warm-start tier
     lists and immovable-background masks, forwarded to whichever backend
@@ -464,8 +387,7 @@ def search_batched(problems: Sequence[Sequence[JobSpec]],
                             for t, mm in zip((CC, ES), pair))
                       for b, pair in zip(busys, pairs)]
         # bucket the padded row count (§12) so metro multi-ward replans with
-        # drifting sizes land on a handful of compiled shapes, and record the
-        # dispatch so `compiled_shape_stats` sees batched traffic too
+        # drifting sizes land on a handful of compiled shapes
         raw_rows = max((len(jobs)
                         + sum(len(v) for v in (rv or {}).values())
                         for jobs, rv in zip(problems, reserveds)), default=0)
@@ -480,11 +402,6 @@ def search_batched(problems: Sequence[Sequence[JobSpec]],
             frozen=None if frozen is None else frozens,
             reserved=None if reserved is None else reserveds,
             pad_to=rows)
-        if raw_rows:
-            n_mov = max(len(jobs) - (sum(map(bool, fr)) if fr is not None
-                                     else 0)
-                        for jobs, fr in zip(problems, frozens))
-            _note_shape(_batched_shape(B, rows, n_mov, pairs, objective))
         with spans.span("scheduler.rescore"):
             return [simulate(jobs, [MACHINES[int(i)] for i in a],
                              machines_per_tier=m, busy_until=b, reserved=rv)
@@ -799,8 +716,6 @@ def search_fleet(ward_jobs: Sequence[Sequence[JobSpec]],
                     max_rounds=max(sweep_max_count, 1),
                     objective=objective, machines_per_tier=pairs,
                     busy_until=busy_pairs, reserved=resvs, pad_to=pad_to)
-                _note_shape(_batched_shape(
-                    B, pad_to, max(map(len, ward_jobs)), pairs, objective))
                 proposals = [[MACHINES[int(i)]
                               for i in assigns[b][:len(ward_jobs[b])]]
                              for b in range(B)]
@@ -838,8 +753,6 @@ def search_fleet(ward_jobs: Sequence[Sequence[JobSpec]],
                     max_rounds=max(sweep_max_count, 1),
                     objective=objective, machines_per_tier=pairs,
                     busy_until=busy_pairs, frozen=frozen, pad_to=pad_to)
-                _note_shape(_batched_shape(
-                    B, pad_to, max(map(len, ward_jobs)), pairs, objective))
                 proposals = [[MACHINES[int(i)]
                               for i in assigns[b][:len(ward_jobs[b])]]
                              for b in range(B)]

@@ -17,16 +17,16 @@ semantics fast to vectorise (DESIGN.md §3.2):
 
 Used for:
   * exact small-n optimum: enumerate all 3^n assignments in one vmap;
-  * `tabu_search_jax`: the fully jitted Algorithm-2 neighbourhood search —
-    every lax.while_loop round scores the whole n x 3 single-move
-    neighbourhood by DELTA EVALUATION (each candidate re-scores only its
-    two affected tiers; one scan per shared tier yields all n toggled
-    stats — DESIGN.md §3.2), so there are NO host<->device round trips
-    until the search terminates;
-  * `tabu_search_batched`: B independent ward instances searched in ONE
-    device call — variable sizes padded with transparent phantom jobs,
-    mixed fleets padded with +inf-busy phantom machines, per-instance
-    convergence flags (DESIGN.md §8);
+  * `tabu_search_batched`: the fully jitted Algorithm-2 neighbourhood
+    search for B independent ward instances (B = 1 for one ward) in ONE
+    device call through one jitted entry, `_tabu_run_packed`. Every
+    lax.while_loop round scores the n x 3 single-move neighbourhood by
+    DELTA EVALUATION (each candidate re-scores only its two affected
+    tiers; one scan per shared tier yields all n toggled stats —
+    DESIGN.md §3.2), so there are NO host<->device round trips until the
+    search terminates. Variable sizes are padded with transparent
+    phantom jobs, mixed fleets with +inf-busy phantom machines, and each
+    instance has its own convergence flag (DESIGN.md §8);
   * random-restart stochastic local search (kept for comparison; it syncs
     to NumPy every iteration);
   * jittable evaluation inside the serving engine's control loop.
@@ -498,7 +498,7 @@ def _greedy_assign_batched(rel, w, proc, trans, valid, busy_c, busy_e):
 
 
 def _run_rounds(assign0, mov_idx, mov_ok, tc, dev, oi, max_moves, binds):
-    """mode="round" inner loop (see `_tabu_run_batched`): steepest
+    """mode="round" inner loop (see `_tabu_body`): steepest
     descent over the S x 3 single-move neighbourhood, one wide
     delta-evaluated round per while_loop iteration, accept each
     instance's best strictly improving move plus a second,
@@ -687,7 +687,7 @@ def _pass_replay(assign, active, mov_idx, mov_ok, tc, dev, oi: int):
 
 def _run_passes(assign0, mov_idx, mov_ok, tc, dev, oi, max_rounds,
                 one_pass):
-    """mode="pass" outer loop (see `_tabu_run_batched`): `one_pass`
+    """mode="pass" outer loop (see `_tabu_body`): `one_pass`
     (`_pass_walk` or `_pass_replay`) per while_loop iteration until no
     ward changed or `max_rounds` passes ran. Returns (assign, totals,
     rounds, evaluations)."""
@@ -715,13 +715,14 @@ def _run_passes(assign0, mov_idx, mov_ok, tc, dev, oi, max_rounds,
     return assign, totals, rounds, evals + (rounds == 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("objective", "greedy_init", "mode"))
-def _tabu_run_batched(assign0, rel, w, proc, trans, movable, mov_idx,
-                      mov_ok, max_rounds, busy_c, busy_e, objective: str,
-                      greedy_init: bool = False, mode: str = "pass"):
+def _tabu_body(assign0, rel, w, proc, trans, movable, mov_idx, mov_ok,
+               max_rounds, busy_c, busy_e, objective: str,
+               greedy_init: bool = False, mode: str = "pass"):
     """Algorithm-2 search for B instances at once, entirely on-device,
-    in one of two shape-dispatched regimes (DESIGN.md §12):
+    in one of two shape-dispatched regimes (DESIGN.md §12). Not jitted
+    itself: the served path traces it inside `_tabu_run_packed`, the
+    search's one jitted entry, where objective, greedy_init and mode are
+    static.
 
     mode="pass" — the mostly-background regime (movable slots are a
     small fraction of the padded rows). Each while_loop iteration is
@@ -833,7 +834,7 @@ def _movable_slots(movable: np.ndarray, mov_idx: np.ndarray,
 
 
 # The packed calling convention of the device search (DESIGN.md §8):
-# `_tabu_run_batched`'s eleven inputs cross to the device as one flat
+# `_tabu_body`'s eleven inputs cross to the device as one flat
 # int32 buffer, fields in this order, float32 bit-cast ("f"), bools as
 # 0/1 ("b"), int32 as is ("i"); the result comes back as one array.
 _PACKED_FIELDS = (("assign0", "i"), ("rel", "f"), ("w", "f"),
@@ -873,7 +874,7 @@ def _field_views(buf: np.ndarray, layout):
 
 
 def _unpack(buf, layout):
-    """`_tabu_run_batched`'s eleven inputs, in its argument order, from
+    """`_tabu_body`'s eleven inputs, in its argument order, from
     the packed buffer, traced: static slices, float32 bit-cast back,
     bools as != 0. Every value comes back bit for bit."""
     fields, _ = _packed_fields(layout)
@@ -892,12 +893,12 @@ def _unpack(buf, layout):
                                              "greedy_init", "mode"))
 def _tabu_run_packed(buf, layout, objective: str,
                      greedy_init: bool = False, mode: str = "pass"):
-    """`_tabu_run_batched` across one buffer each way: the inputs from the
+    """`_tabu_body` across one buffer each way: the inputs from the
     packed buffer (`_unpack`), the result as one (B, n_max + 2) int32
     array, the assignment in the first n_max columns, the float32
     objective bit-cast into the next and the batch's tier-evaluation
     count in the last. The round count stays on the device."""
-    assign, totals, _, evals = _tabu_run_batched(
+    assign, totals, _, evals = _tabu_body(
         *_unpack(buf, layout), objective, greedy_init=greedy_init,
         mode=mode)
     return jnp.concatenate(
@@ -973,10 +974,12 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
     keep searching; the device call returns when every ward has converged
     (or after max_rounds accept-as-you-go passes over the movable slots —
     the Python search's max_count, default 50). Each ward's
-    trajectory is identical to a solo `tabu_search_jax` run — same pass
-    code, same tie-breaks — which the parity suite pins (DESIGN.md §8).
+    trajectory is identical to its solo (B = 1) run — same pass code,
+    same tie-breaks — which the parity suite pins (DESIGN.md §8).
     Recompiles per (B, n_max, movable bucket S, padded machine counts,
-    objective); replans reusing one shape hit the cache.
+    objective); replans reusing one shape hit jit's cache. Without a
+    bucketed `pad_to`, which `scheduler.search` passes, every new
+    instance size retraces.
     """
     B = len(batch_jobs)
     if B == 0:
@@ -1073,38 +1076,6 @@ def tabu_search_batched(batch_jobs: Sequence[Sequence[JobSpec]],
     return totals, [out[b, :sizes[b]] for b in range(B)]
 
 
-def tabu_search_jax(jobs: Sequence[JobSpec],
-                    initial: Sequence[int] | np.ndarray | None = None,
-                    *, max_rounds: int | None = None,
-                    objective: str = "weighted",
-                    machines_per_tier: Tuple[int, int] = (1, 1),
-                    busy_until=None, frozen=None, reserved=None):
-    """Fully-jitted Algorithm-2 neighbourhood search. Returns
-    (best objective value, best assignment as an (n,) int array).
-
-    The whole search — delta-evaluated n x 3 neighbourhood rounds, move
-    acceptance, termination — runs inside one jitted lax.while_loop; the
-    only transfer is the final result. Each accepted move strictly
-    improves the objective, so the search terminates at a 1-move local
-    optimum of the same neighbourhood the Python tabu search explores.
-    This is the B = 1 case of `tabu_search_batched` (same compiled round
-    code), so solo and batched runs follow identical trajectories.
-
-    busy_until: optional (cloud_times, edge_times) initial machine free
-    times — online replans pass the committed fleet state here, so the
-    searched objective is the commit objective (DESIGN.md §7). Traced, so
-    successive replans hit the same compiled search."""
-    vals, assigns = tabu_search_batched(
-        [jobs], None if initial is None else [list(initial)],
-        max_rounds=max_rounds, objective=objective,
-        machines_per_tier=(int(machines_per_tier[0]),
-                           int(machines_per_tier[1])),
-        busy_until=None if busy_until is None else [busy_until],
-        frozen=None if frozen is None else [frozen],
-        reserved=None if reserved is None else [reserved])
-    return float(vals[0]), assigns[0]
-
-
 def stochastic_search(jobs: Sequence[JobSpec], key,
                       initial: np.ndarray, *, iters: int = 200,
                       pop: int = 256, objective: str = "weighted",
@@ -1116,7 +1087,7 @@ def stochastic_search(jobs: Sequence[JobSpec], key,
     and keeps the best. Converges to (at least) a 1-swap local optimum of
     the same neighbourhood Algorithm 2 explores, but evaluates the whole
     neighbourhood batch in one device call. Kept as the host-synced
-    baseline for `tabu_search_jax` (see benchmarks/scheduler_scale.py).
+    baseline for `tabu_search_batched` (see benchmarks/scheduler_scale.py).
 
     machines_per_tier / busy_until describe the fleet the schedule runs on
     (DESIGN.md §7) and are threaded into every candidate evaluation — the

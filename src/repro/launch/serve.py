@@ -296,9 +296,7 @@ def run_metro(wards=None, hours=None, seed=0, cloud_machines=2,
     check_determinism=True replays every policy twice on a fresh engine
     and raises unless the event logs hash identically — the seeded-chaos
     determinism contract (DESIGN.md §11). The search backend is pinned
-    to the Python path when no jax_threshold is given, because the
-    compiled-shape cache is call-order-dependent across runs in one
-    process (see metro.engine's determinism note). The verification
+    to the Python path when no jax_threshold is given. The verification
     rerun is UNTRACED, so with `trace` set the hash comparison doubles
     as a live traced-vs-untraced CRC-parity check (DESIGN.md §15).
 
@@ -330,8 +328,7 @@ def run_metro(wards=None, hours=None, seed=0, cloud_machines=2,
     # fleet's joint fixed point gets small per-event budgets: each event
     # only needs local repair on top of the previous one (DESIGN.md §10).
     # jax_threshold pins the search backend of the replanning policies
-    # (greedy/shed never search) — pass it for call-order-independent
-    # runs (see metro.engine's determinism note).
+    # (greedy/shed never search)
     kwargs = {"fleet": dict(max_count=2, max_sweeps=1,
                             jax_threshold=jax_threshold),
               "tabu": dict(jax_threshold=jax_threshold)}
@@ -384,10 +381,6 @@ def run_metro(wards=None, hours=None, seed=0, cloud_machines=2,
                     f"{rerun_hash:#x})")
         s = res.summary()
         s["event_log_hash"] = log_hash
-        # global cumulative §3.3 shape-cache counters at this point of
-        # the process — evictions staying 0 is a gate invariant, so it
-        # belongs where users look, not only in the benchmark
-        s["compiled_shapes"] = scheduler.compiled_shape_stats()
         out[name] = s
         if res.trace is not None:
             traced_runs[name] = res
@@ -421,10 +414,6 @@ def run_metro(wards=None, hours=None, seed=0, cloud_machines=2,
             jobs_done = max(out["greedy"]["completions"], 1)
             print(f"tabu-replan miss-rate improvement vs greedy: "
                   f"{g / max(t, 0.5 / jobs_done):.2f}x")
-    if verbose:
-        cs = scheduler.compiled_shape_stats()
-        print(f"compiled shapes: size={cs['size']} hits={cs['hits']} "
-              f"misses={cs['misses']} evictions={cs['evictions']}")
     if trace is not None:
         multi = len(traced_runs) > 1
         for name, res in traced_runs.items():
@@ -436,13 +425,10 @@ def run_metro(wards=None, hours=None, seed=0, cloud_machines=2,
                       f"-> {path}")
     if postmortem and verbose:
         for name, res in traced_runs.items():
-            print(res.trace.format_postmortem(
-                name, res.profile,
-                out[name].get("compiled_shapes")))
+            print(res.trace.format_postmortem(name, res.profile))
     if postmortem_out is not None:
-        report = {name: res.trace.postmortem_json(
-            name, res.profile, out[name].get("compiled_shapes"))
-            for name, res in traced_runs.items()}
+        report = {name: res.trace.postmortem_json(name, res.profile)
+                  for name, res in traced_runs.items()}
         with open(postmortem_out, "w") as f:
             json.dump(report, f, indent=2)
         if verbose:

@@ -56,13 +56,7 @@ four knobs default OFF, reproducing the PR 6 engine event-for-event.
 Completion events are scheduled from commitment end times and validated
 lazily on pop (a replan that re-times a commitment simply strands the
 stale event), the standard DES invalidation scheme — so the event log is
-a deterministic function of (traces, fleet events, policy) and of the
-`scheduler.search` dispatch state: search-based policies inherit the
-§3.3 compiled-shape cache, so a process that force-compiled a shape
-before the run may legitimately commit a different (equally exact)
-local optimum than a fresh process. Pin `jax_threshold` on the policy
-for call-order-independent runs; the committed benchmarks run in a
-fresh process with a fixed section order.
+a deterministic function of (traces, fleet events, policy).
 """
 from __future__ import annotations
 

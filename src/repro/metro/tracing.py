@@ -414,11 +414,9 @@ class MetroTrace:
         return out
 
     def format_postmortem(self, policy: str = "?",
-                          profile: Optional[dict] = None,
-                          compiled_shapes: Optional[dict] = None) -> str:
+                          profile: Optional[dict] = None) -> str:
         """Human-readable postmortem block (serve --metro --postmortem):
-        the blame table plus the engine self-profile and compiled-shape
-        cache counters when available."""
+        the blame table plus the engine self-profile when available."""
         lines = [f"postmortem[{policy}]: {len(self.attribution())} "
                  f"missed/shed jobs of {len(self.rows)} finished"]
         table = self.blame_table()
@@ -447,22 +445,14 @@ class MetroTrace:
                                             key=lambda kv: -kv[1])) +
                 f" (total {profile.get('seconds_total', 0.0)*1e3:.1f}ms, "
                 f"{profile.get('events', 0)} events)")
-        if compiled_shapes:
-            lines.append(
-                f"  shape cache: size={compiled_shapes.get('size', 0)} "
-                f"hits={compiled_shapes.get('hits', 0)} "
-                f"misses={compiled_shapes.get('misses', 0)} "
-                f"evictions={compiled_shapes.get('evictions', 0)}")
         return "\n".join(lines)
 
     def postmortem_json(self, policy: str = "?",
-                        profile: Optional[dict] = None,
-                        compiled_shapes: Optional[dict] = None) -> dict:
+                        profile: Optional[dict] = None) -> dict:
         return {"policy": policy, "finished": len(self.rows),
                 "missed": self.attribution(missed_only=True),
                 "blame": self.blame_table(),
-                "profile": profile or {},
-                "compiled_shapes": compiled_shapes or {}}
+                "profile": profile or {}}
 
     # ---------------------------------------------------------- exporters
     def to_jsonl(self, path: str) -> int:

@@ -211,9 +211,9 @@ class TestRuleFixtures:
 
     def test_r006_flags_raw_kernel_call_outside_dispatcher(self, tmp_path):
         code = """
-            from repro.core.scheduler_jax import tabu_search_jax
+            from repro.core.scheduler_jax import tabu_search_batched
             def plan(jobs):
-                return tabu_search_jax(jobs)
+                return tabu_search_batched([jobs])
         """
         assert rule_ids(lint_snippet(
             tmp_path, code, name="metro/policies.py")) == ["R006"]

@@ -149,8 +149,8 @@ class TestFrozenJobs:
             frozen = [bool(rng.integers(2)) for _ in jobs]
             init = [int(rng.integers(3)) if f else 2
                     for f, _ in zip(frozen, jobs)]
-            v, a = scheduler_jax.tabu_search_jax(
-                jobs, initial=init, frozen=frozen)
+            (v,), (a,) = scheduler_jax.tabu_search_batched(
+                [jobs], [init], frozen=[frozen])
             for i, f in enumerate(frozen):
                 if f:
                     assert int(a[i]) == init[i]
@@ -448,8 +448,7 @@ class TestContentionIntervalGate:
             "gap_closed": 0.95, "improvement_vs_naive": 10.0,
             "wards_per_s": 5.0, "fraction_of_batched": 0.03,
             "parity_with_phantom": True,
-            "compiled_shapes": {"size": 2, "hits": 10, "misses": 2,
-                                "evictions": 0}}}
+            "compiled_programs": 0}}
         import copy
         return base, copy.deepcopy(base)
 
@@ -484,11 +483,14 @@ class TestContentionIntervalGate:
         assert any("parity_with_phantom" in p
                    for p in compare(committed, fresh, tolerance=0.99))
 
-    def test_eviction_fails(self):
+    def test_compile_growth_fails(self):
+        """More programs compiled than the committed run is a hard
+        failure: tolerance never excuses shape churn."""
         compare = self._compare()
         committed, fresh = self._reports()
-        fresh["contention_interval"]["compiled_shapes"]["evictions"] = 3
-        assert any("evictions" in p for p in compare(committed, fresh))
+        fresh["contention_interval"]["compiled_programs"] = 1
+        assert any("compiled_programs" in p
+                   for p in compare(committed, fresh, tolerance=0.99))
 
     def test_missing_section_is_not_gated(self):
         compare = self._compare()

@@ -8,7 +8,7 @@ from dataclasses import replace
 
 import pytest
 
-from repro.core import problems, scheduler
+from repro.core import problems
 from repro.metro import TabuPolicy, simulate_metro
 from repro.utils import spans
 
@@ -26,20 +26,6 @@ def table6_stream(periods=2, period=25.0):
 def replay(**kw):
     return simulate_metro(table6_stream(), TabuPolicy(jax_threshold=0),
                           machines_per_tier=MPT, **kw)
-
-
-@pytest.fixture(autouse=True, scope="module")
-def _isolate_compiled_shapes():
-    """Every replay here forces the JAX search (jax_threshold=0), which
-    records its bucketed shape in the module-global fast-path set;
-    restore the set so later test modules keep their CPU default
-    dispatch."""
-    saved = set(scheduler._COMPILED_SHAPES)
-    stats = dict(scheduler._SHAPE_STATS)
-    yield
-    scheduler._COMPILED_SHAPES.clear()
-    scheduler._COMPILED_SHAPES.update(saved)
-    scheduler._SHAPE_STATS.update(stats)
 
 
 @pytest.fixture(scope="module")

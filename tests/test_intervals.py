@@ -19,19 +19,6 @@ from repro.core.simulator import (MACHINES, JobSpec, Reservation,
 from repro.core.tiers import CC, ED, ES
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _isolate_compiled_shapes():
-    """Tests here force the JAX path (jax_threshold=0), which records
-    bucketed shapes in the module-global fast-path set — restore it so
-    later test modules keep their CPU default dispatch."""
-    saved = set(scheduler._COMPILED_SHAPES)
-    stats = dict(scheduler._SHAPE_STATS)
-    yield
-    scheduler._COMPILED_SHAPES.clear()
-    scheduler._COMPILED_SHAPES.update(saved)
-    scheduler._SHAPE_STATS.update(stats)
-
-
 def _random_jobs(rng, n):
     return [JobSpec(name=f"J{i}", release=float(rng.integers(0, 30)),
                     weight=float(rng.integers(1, 4)),

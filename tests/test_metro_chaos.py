@@ -287,8 +287,8 @@ def test_every_pack_is_deterministic_and_crash_consistent(pack):
 
 
 def test_search_policy_deterministic_on_crash_pack():
-    # the replanning path through kills/failovers, pinned off the JAX
-    # dispatch cache (jax_threshold) so the run is call-order-independent
+    # the replanning path through kills/failovers, pinned to the Python
+    # search (jax_threshold) so the run is the same on any platform
     runs = [_run_pack("edge_brownout", TabuPolicy(jax_threshold=10 ** 9),
                       seed=1, wards=2, horizon=40.0) for _ in range(2)]
     (sc, a), (_, b) = runs

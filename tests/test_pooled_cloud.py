@@ -10,7 +10,7 @@ from dataclasses import replace
 
 import pytest
 
-from repro.core import problems, scheduler
+from repro.core import problems
 from repro.core.scheduler_jax import tabu_search_batched
 from repro.core.simulator import JobSpec, Reservation, simulate
 from repro.core.tiers import CC, ED, ES
@@ -21,19 +21,6 @@ from test_metro import _check_schedule_invariants
 
 TIERS = (CC, ES, ED)
 MPT = {CC: 4, ES: 1}
-
-
-@pytest.fixture(autouse=True, scope="module")
-def _isolate_compiled_shapes():
-    """The replays force the JAX search (jax_threshold=0), which records
-    its bucketed shapes in the module-global fast-path set; restore the
-    set so later test modules keep their CPU default dispatch."""
-    saved = set(scheduler._COMPILED_SHAPES)
-    stats = dict(scheduler._SHAPE_STATS)
-    yield
-    scheduler._COMPILED_SHAPES.clear()
-    scheduler._COMPILED_SHAPES.update(saved)
-    scheduler._SHAPE_STATS.update(stats)
 
 
 def _burst_job(name):

@@ -4,6 +4,7 @@ the fleet-aware stochastic search, and the ValueError busy guards.
 
 Instances are integer-valued (float32-exact), so "identical trajectories"
 is testable as bit-identical objectives after exact re-simulation."""
+import functools
 import os
 import sys
 
@@ -47,16 +48,16 @@ def _exact(jobs, assign, mpt=(1, 1), busy=None, objective="weighted"):
 
 
 def _assert_batch_parity(batch, mpts, busys, objective="weighted"):
-    """Batched search == per-instance tabu_search_jax, bit-identical after
+    """Batched search == per-instance (B = 1) runs, bit-identical after
     exact re-simulation, and reported values match the simulator."""
     vals, assigns = scheduler_jax.tabu_search_batched(
         batch, objective=objective, machines_per_tier=mpts,
         busy_until=busys)
     for jobs, mpt, busy, vb, ab in zip(batch, mpts, busys, vals, assigns):
         assert len(ab) == len(jobs)
-        v1, a1 = scheduler_jax.tabu_search_jax(
-            jobs, objective=objective, machines_per_tier=mpt,
-            busy_until=busy)
+        _, (a1,) = scheduler_jax.tabu_search_batched(
+            [jobs], objective=objective, machines_per_tier=[mpt],
+            busy_until=[busy])
         got = _exact(jobs, ab, mpt, busy, objective)
         solo = _exact(jobs, a1, mpt, busy, objective)
         assert got == solo, (got, solo)
@@ -138,8 +139,8 @@ class TestPhantomPadding:
         for objective in ("weighted", "unweighted", "last"):
             vals, assigns = scheduler_jax.tabu_search_batched(
                 [small, big], objective=objective)
-            v_solo, _ = scheduler_jax.tabu_search_jax(
-                small, objective=objective)
+            (v_solo,), _ = scheduler_jax.tabu_search_batched(
+                [small], objective=objective)
             assert vals[0] == v_solo
             assert len(assigns[0]) == 4
 
@@ -206,8 +207,17 @@ def _captured_search(monkeypatch, batch, kw):
     return scheduler_jax.tabu_search_batched(batch, **kw), seen
 
 
+@functools.cache
+def _unpacked_kernel():
+    """The search's traced body jitted alone, on the eleven arguments
+    that `_tabu_run_packed` unpacks from its buffer."""
+    import jax
+    return jax.jit(scheduler_jax._tabu_body,
+                   static_argnames=("objective", "greedy_init", "mode"))
+
+
 def _host_inputs(seen):
-    """`_tabu_run_batched`'s eleven host arguments read off the buffer:
+    """`_tabu_body`'s eleven host arguments read off the buffer:
     float32 and int32 fields as they are, bools from 0/1."""
     views = scheduler_jax._field_views(seen["buf"], seen["layout"])
     kinds = dict(scheduler_jax._PACKED_FIELDS)
@@ -269,7 +279,7 @@ class TestPackedBoundary:
         batch, kw = _packed_case(size, regime)
         (vals, assigns), seen = _captured_search(monkeypatch, batch, kw)
         host = _host_inputs(seen)
-        assign, totals, _, _ = scheduler_jax._tabu_run_batched(
+        assign, totals, _, _ = _unpacked_kernel()(
             *(host[name] for name, _ in scheduler_jax._PACKED_FIELDS),
             seen["objective"], greedy_init=seen["greedy_init"],
             mode=seen["mode"])
@@ -352,7 +362,7 @@ def _pass_runs(host, objective):
         return ref, rep[:3], rep[3]
 
     ref, rep, evals = both(*args)
-    kernel = scheduler_jax._tabu_run_batched(*args, objective, mode="pass")
+    kernel = _unpacked_kernel()(*args, objective, mode="pass")
     out = {"reference": ref, "replay": rep, "kernel": kernel[:3]}
     return {k: tuple(np.asarray(x) for x in v) for k, v in out.items()}, \
         int(evals)
@@ -385,9 +395,9 @@ def _metro_ward(rng, n_jobs, objective, fleet, n_resv=27, converged=False,
         initial[-1] = 2
     busy = ([float(rng.integers(0, 20))], [])
     if converged:
-        _, plan = scheduler_jax.tabu_search_jax(
-            jobs, initial, objective=objective, machines_per_tier=fleet,
-            busy_until=busy, reserved=resv)
+        _, (plan,) = scheduler_jax.tabu_search_batched(
+            [jobs], [initial], objective=objective,
+            machines_per_tier=[fleet], busy_until=[busy], reserved=[resv])
         initial = [int(x) for x in plan]
     return jobs, initial, resv, busy
 

@@ -1,6 +1,9 @@
 """Fast-path scheduler tests: incremental ScheduleState invariants, the
 rescore-after-move (float drift) regression, the randomized Python/JAX
 parity suite (>= 200 instances), and the size-dispatched search."""
+import zlib
+from dataclasses import replace
+
 import numpy as np
 import pytest
 
@@ -161,7 +164,7 @@ class TestTabuSearchJax:
     def test_reaches_exact_optimum_on_small_instances(self):
         for seed in range(5):
             jobs = _random_jobs(np.random.default_rng(seed), 7)
-            v, a = scheduler_jax.tabu_search_jax(jobs)
+            (v,), (a,) = scheduler_jax.tabu_search_batched([jobs])
             opt, _ = scheduler_jax.exact_optimum_jax(jobs)
             assert v <= opt * 1.05 + 1e-6
             # the returned value is the exact simulation of the returned
@@ -171,7 +174,7 @@ class TestTabuSearchJax:
 
     def test_improves_on_greedy_start(self):
         jobs = table6_jobs()
-        v, _ = scheduler_jax.tabu_search_jax(jobs)
+        (v,), _ = scheduler_jax.tabu_search_batched([jobs])
         greedy = simulate(jobs, scheduler.greedy_schedule(jobs))
         assert v <= greedy.weighted_sum + 1e-6
 
@@ -193,6 +196,19 @@ class TestSearchDispatch:
             assert s.weighted_sum <= \
                 scheduler.all_on_tier(jobs, t).weighted_sum + 1e-6
 
+    def test_sizes_in_one_bucket_share_a_program(self):
+        """`search` pads the device search's rows to a 16-row bucket
+        (DESIGN.md §12): n=9 and n=10 ride one compiled program, and
+        n=20 needs the next bucket's."""
+        mpt = {CC: 2, ES: 1}
+        kernel = scheduler_jax._tabu_run_packed
+        kernel.clear_cache()
+        for n, grows in ((9, True), (10, False), (20, True)):
+            before = kernel._cache_size()
+            jobs = _random_jobs(np.random.default_rng(n), n)
+            scheduler.search(jobs, machines_per_tier=mpt, jax_threshold=0)
+            assert (kernel._cache_size() > before) == grows, n
+
     def test_online_replan_through_dispatcher(self):
         from repro.core import online
         jobs = _random_jobs(np.random.default_rng(3), 12)
@@ -206,69 +222,50 @@ class TestSearchDispatch:
                     - 1e-9
 
 
-# ------------------------------------------------- compiled-shape dispatch
-class TestCompiledShapeCache:
-    def test_second_same_shape_call_uses_jax(self, monkeypatch):
-        """A CPU `search` whose BUCKETED (rows, movable, fleet,
-        objective) shape an earlier call already compiled dispatches to
-        the jitted backend (ROADMAP: repeating replans stop paying
-        Python-path costs) — and the §12 bucketing means every size in
-        the same 16-slot bucket rides the one compiled kernel, so metro
-        load's per-event size drift keeps hitting."""
-        monkeypatch.setattr(scheduler, "_COMPILED_SHAPES", set())
-        calls = []
-        real = scheduler_jax.tabu_search_batched
+# ------------------------------------------------ backend follows the input
+def _search(jax_threshold):
+    jobs = _random_jobs(np.random.default_rng(0), 9)
+    return scheduler.search(jobs, machines_per_tier={CC: 2, ES: 1},
+                            jax_threshold=jax_threshold).assignment()
 
-        def spy(*args, **kw):
-            calls.append(kw.get("machines_per_tier"))
-            return real(*args, **kw)
 
-        monkeypatch.setattr(scheduler_jax, "tabu_search_batched", spy)
-        jobs = _random_jobs(np.random.default_rng(0), 9)
-        mpt = {CC: 2, ES: 1}
+def _search_batched(jax_threshold):
+    probs = [_random_jobs(np.random.default_rng(s), 9) for s in range(3)]
+    return [s.assignment() for s in scheduler.search_batched(
+        probs, min_batch=99, jax_threshold=jax_threshold)]
 
-        first = scheduler.search(jobs, machines_per_tier=mpt)
-        assert calls == []                      # below threshold: Python
-        forced = scheduler.search(jobs, machines_per_tier=mpt,
-                                  jax_threshold=0)
-        assert len(calls) == 1                  # explicit: compiles shape
-        cached = scheduler.search(jobs, machines_per_tier=mpt)
-        assert len(calls) == 2                  # same shape: jitted now
-        assert cached.weighted_sum == forced.weighted_sum
-        assert first.weighted_sum > 0
 
-        other = _random_jobs(np.random.default_rng(1), 10)
-        scheduler.search(other, machines_per_tier=mpt)
-        assert len(calls) == 3                  # same 16-bucket: jitted
-        bigger = _random_jobs(np.random.default_rng(2), 20)
-        scheduler.search(bigger, machines_per_tier=mpt)
-        assert len(calls) == 3                  # new bucket: Python path
-        scheduler.search(jobs, machines_per_tier={CC: 1, ES: 1})
-        assert len(calls) == 3                  # new fleet: Python path
-        scheduler.search(jobs, machines_per_tier=mpt,
-                         objective="unweighted")
-        assert len(calls) == 3                  # new objective: Python
+def _metro_log_crc(jax_threshold):
+    from repro.metro import TabuPolicy, simulate_metro
+    traces = [[replace(j, name=f"{j.name}.{p}", release=j.release + 25.0 * p)
+               for p in range(2) for j in table6_jobs()]]
+    res = simulate_metro(traces, TabuPolicy(jax_threshold=jax_threshold),
+                         machines_per_tier={CC: 1, ES: 1})
+    return zlib.crc32(repr(res.event_log).encode())
 
-    def test_shape_stats_and_cap(self, monkeypatch):
-        """`compiled_shape_stats` counts hits/misses, and a miss at the
-        cap evicts the whole cache instead of growing without bound."""
-        monkeypatch.setattr(scheduler, "_COMPILED_SHAPES", set())
-        monkeypatch.setattr(scheduler, "_SHAPE_STATS",
-                            {"hits": 0, "misses": 0, "evictions": 0})
-        jobs = _random_jobs(np.random.default_rng(0), 9)
-        scheduler.search(jobs, jax_threshold=0)       # miss, compiles
-        scheduler.search(jobs, jax_threshold=0)       # hit
-        stats = scheduler.compiled_shape_stats()
-        assert stats["size"] == 1
-        assert stats["misses"] == 1 and stats["hits"] == 1
-        assert stats["evictions"] == 0
 
-        monkeypatch.setattr(scheduler, "_COMPILED_SHAPES_CAP", 1)
-        scheduler.search(jobs, jax_threshold=0,
-                         objective="unweighted")      # miss AT cap
-        stats = scheduler.compiled_shape_stats()
-        assert stats["evictions"] == 1
-        assert stats["size"] == 1                     # cleared, re-added
+@pytest.mark.parametrize("run", [_search, _search_batched, _metro_log_crc],
+                         ids=["search", "search_batched", "metro"])
+def test_backend_is_a_function_of_the_input(run, monkeypatch):
+    """The search backend follows the call's own arguments and the
+    platform (DESIGN.md §3.3): below the threshold on the CPU the Python
+    path runs, and returns the same answer, before and after a forced
+    device search of the same bucketed shape."""
+    calls = []
+    real = scheduler_jax.tabu_search_batched
+
+    def spy(*args, **kw):
+        calls.append(len(args[0]))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(scheduler_jax, "tabu_search_batched", spy)
+    fresh = run(None)
+    assert calls == []                          # CPU default: Python
+    run(0)
+    forced = len(calls)
+    assert forced > 0                           # explicit: device search
+    assert run(None) == fresh
+    assert len(calls) == forced                 # still Python
 
 
 # ----------------------------------------- batched initial/frozen threading

@@ -75,32 +75,6 @@ def test_icu_forward_compiles_for_v5e(one_chip, cfg, monkeypatch):
     ("round", 32, 112, 112),     # 32 wards x 100 jobs, independent plans
     ("pass", 32, 128, 16),       # a sweep against interval reservations
 ])
-def test_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows, slots):
-    f32, i32 = jnp.float32, jnp.int32
-    n_tiers = scheduler_jax.N_MACHINES
-    args = (_spec((batch, rows), i32, one_chip),             # assign0
-            _spec((batch, rows), f32, one_chip),             # rel
-            _spec((batch, rows), f32, one_chip),             # w
-            _spec((batch, rows, n_tiers), f32, one_chip),    # proc
-            _spec((batch, rows, n_tiers), f32, one_chip),    # trans
-            _spec((batch, rows), jnp.bool_, one_chip),       # movable
-            _spec((batch, slots), i32, one_chip),            # mov_idx
-            _spec((batch, slots), jnp.bool_, one_chip),      # mov_ok
-            _spec((), i32, one_chip),                        # max_rounds
-            _spec((batch, 1), f32, one_chip),                # busy_c
-            _spec((batch, 1), f32, one_chip))                # busy_e
-    compiled = scheduler_jax._tabu_run_batched.lower(
-        *args, objective="weighted", mode=mode).compile()
-    assert compiled.memory_analysis() is not None
-    # the batched search picks this regime at this shape
-    assert scheduler_jax.kernel_regime(slots, rows) == mode
-    assert "while" in compiled.as_text()
-
-
-@pytest.mark.parametrize("mode,batch,rows,slots", [
-    ("round", 32, 112, 112),
-    ("pass", 32, 128, 16),
-])
 def test_packed_tabu_search_compiles_for_v5e(one_chip, mode, batch, rows,
                                              slots):
     # the served entry: one int32 buffer in, one (B, rows + 2) array out
